@@ -472,7 +472,7 @@ def smoke() -> None:
     print(f"emulated rate-1 parity ok: max param diff {d:.2e}")
 
     # 4. same on the shard_map backend (subprocess: 4 virtual devices)
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4",
                PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
                                        "src"))

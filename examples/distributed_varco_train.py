@@ -68,11 +68,14 @@ def main():
     ap.add_argument("--out", default="experiments/run")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
     from repro.core import CommPolicy
     from repro.graph import citation_graph, copurchase_graph
     from repro.train import train_gnn
     from repro.train.checkpoint import save
     from repro.train.metrics import write_csv
+
+    enable_compile_cache()
 
     gen = citation_graph if args.dataset == "arxiv" else copurchase_graph
     graph = gen(n=args.nodes) if args.feat_dim is None \

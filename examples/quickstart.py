@@ -12,12 +12,14 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.compile_cache import enable_compile_cache      # noqa: E402
 from repro.core import FULL_COMM, NO_COMM, varco          # noqa: E402
 from repro.graph import citation_graph                     # noqa: E402
 from repro.train import train_gnn                          # noqa: E402
 
 
 def main():
+    enable_compile_cache()
     epochs = 100
     graph = citation_graph(n=3000, seed=0)
     print(f"graph: {graph.num_nodes} nodes, {graph.num_edges} edges, "

@@ -25,10 +25,12 @@ def main():
     ap.add_argument("--new-tokens", type=int, default=32)
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
     from repro.configs import get_config
     from repro.launch.steps import make_decode_step, make_prefill_step
     from repro.models.transformer import init_lm
 
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=True)
     params = init_lm(jax.random.key(0), cfg)
     rng = np.random.default_rng(0)

@@ -26,6 +26,7 @@ import numpy as np  # noqa: E402
 
 
 def main():
+    from repro.compile_cache import enable_compile_cache
     from repro.configs import get_config
     from repro.core import FULL_COMM, varco
     from repro.dist.grad_compress import make_dp_mesh, \
@@ -34,6 +35,7 @@ def main():
     from repro.models.transformer import init_lm
     from repro.nn.modules import param_count
 
+    enable_compile_cache()
     cfg = get_config("granite-3-2b", smoke=True)
     steps = 40
     mesh = make_dp_mesh(4)

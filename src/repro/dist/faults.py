@@ -357,16 +357,14 @@ def make_fault_train_step(cfg, policy, opt, meta, mesh=None, sync: str = "grad",
     import jax
     import jax.numpy as jnp
     from jax import lax
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.dist.gnn_parallel import (AXIS, COMPILED_CACHE_SIZE,
                                          _local_loss_fn,
                                          _make_aggregate_emulated,
                                          _make_aggregate_shard,
-                                         _packed_pair_k_for,
-                                         _packed_pair_w_for, _pmean_inexact,
-                                         _snap_width)
+                                         _pmean_inexact, plan_statics)
     from repro.dist.ratectl.driver import _auto_metrics, exchange_widths
     from repro.kernels.varco_pack import LANE
     from repro.nn.gnn import gnn_forward, masked_loss_and_correct
@@ -401,18 +399,8 @@ def make_fault_train_step(cfg, policy, opt, meta, mesh=None, sync: str = "grad",
     cache_size = COMPILED_CACHE_SIZE if compiled_cache_size is None \
         else compiled_cache_size
 
-    def _plan_widths(plan):
-        if plan.widths is None:
-            return None, ()
-        wm = np.asarray(plan.widths, np.float32)
-        wm = np.vectorize(_snap_width)(wm).astype(np.float32)
-        ww = _packed_pair_w_for(meta, wm)
-        return (wm, ww) if ww else (None, ())
-
     def _host_plan(plan, fskip, dead, fcache):
-        rm = np.asarray(plan.rates, np.float32)
-        kb = _packed_pair_k_for(meta, rm)
-        wm, ww = _plan_widths(plan)
+        rm, kb, wm, ww, _ = plan_statics(meta, plan)
         rs = 1.0
         if policy.mode == "varco" and q > 1:
             rs = float(rm[~np.eye(q, dtype=bool)].mean())
@@ -518,7 +506,7 @@ def make_fault_train_step(cfg, policy, opt, meta, mesh=None, sync: str = "grad",
             make_worker(kblocks, wire_w), mesh=mesh,
             in_specs=(P(), P(), P(AXIS), P(), P(), P(), P(), P(),
                       P(AXIS), P()),
-            out_specs=(P(), P(), P(), P(AXIS)), check_rep=False))
+            out_specs=(P(), P(), P(), P(AXIS)), check_vma=False))
 
     def step(params, opt_state, graph, key, plan, fskip, dead,
              cache=(), fcache=()):

@@ -69,7 +69,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.collectives import (compressed_all_gather,
@@ -511,6 +511,31 @@ def _packed_store_w(meta: DistMeta, width_map) -> int:
     return max(ws)
 
 
+def plan_statics(meta: DistMeta, plan) -> tuple:
+    """Host-side quantisation of a concrete rate plan to what a step
+    compiles on: ``(rate_map, packed_k, width_map, wire_w, store_w)``.
+
+    ``rate_map`` is ``plan.rates`` as f32 numpy and ``packed_k`` its
+    static kept-block maxima (:func:`_packed_pair_k_for`); ``width_map``
+    holds the widths snapped to the storage grid (:func:`_snap_width`)
+    and ``wire_w`` their distinct sub-32 tuple
+    (:func:`_packed_pair_w_for`) — ``(None, ())`` when no pair quantises,
+    which compiles the exact pre-quantisation program; ``store_w`` is the
+    sub-byte storage width (:func:`_packed_store_w`).  Every plan-driven
+    step (auto, fault, serving; both backends) derives its jit-static
+    arguments here."""
+    rm = np.asarray(plan.rates, np.float32)
+    wm, ww = None, ()
+    if plan.widths is not None:
+        wm = np.vectorize(_snap_width)(
+            np.asarray(plan.widths, np.float32)).astype(np.float32)
+        ww = _packed_pair_w_for(meta, wm)
+        if not ww:
+            wm = None
+    return rm, _packed_pair_k_for(meta, rm), wm, ww, \
+        _packed_store_w(meta, wm)
+
+
 def _rate_tensor_layers(meta: DistMeta, rate_map) -> int:
     """Static layer count of a rate operand: 1 for ``None`` / ``[Q, Q]``
     pair maps, ``L`` for a per-layer ``[L, Q, Q]`` tensor — which must
@@ -639,6 +664,21 @@ def _fault_live(q: int, fskip, dead, live):
     if dead is not None:
         lv = lv * (1.0 - jnp.asarray(dead, jnp.float32))
     return lv
+
+
+def _per_part(fn, *stacks):
+    """``fn`` over the leading ``[Q]`` axis of ``stacks``.
+
+    On TPU one partition at a time (``lax.map``), so the partitions' edge
+    scatter-adds stay unbatched: XLA:TPU's compile time for a scatter-add
+    grows with its operand, and ``vmap`` makes one ``[Q, P+1, F]``
+    scatter per exchange — about two minutes of compilation per program
+    at OGBN-Arxiv scale, where the per-partition body takes seconds.
+    Elsewhere ``vmap``, the program the CPU parity tests pin (the two
+    agree to f32 rounding, not bitwise)."""
+    if jax.default_backend() == "tpu":
+        return jax.lax.map(lambda a: fn(*a), stacks)
+    return jax.vmap(fn)(*stacks)
 
 
 def _make_aggregate_emulated(graph: dict, meta: DistMeta, policy: CommPolicy,
@@ -1019,9 +1059,9 @@ def _make_aggregate_emulated(graph: dict, meta: DistMeta, policy: CommPolicy,
         del li
         f = x.shape[-1]
         if not policy.communicates:                    # No-Comm baseline
-            return jax.vmap(lambda xq, ld, ls, w:
-                            jnp.zeros((p_sz + 1, f), x.dtype)
-                            .at[ld].add(w[:, None] * xq[ls])[:p_sz])(
+            return _per_part(lambda xq, ld, ls, w:
+                             jnp.zeros((p_sz + 1, f), x.dtype)
+                             .at[ld].add(w[:, None] * xq[ls])[:p_sz],
                 x, graph["local_dst"], graph["local_src"],
                 graph["local_w_iso"])
 
@@ -1041,8 +1081,8 @@ def _make_aggregate_emulated(graph: dict, meta: DistMeta, policy: CommPolicy,
                 rem = rem.at[rd].add(rw[:, None] * halo_c[rs])
                 return loc + rem[:p_sz]
 
-            return jax.vmap(part_p2p)(
-                x, graph["ell_nbr"], ell_w, graph["ell_rnbr"],
+            return _per_part(
+                part_p2p, x, graph["ell_nbr"], ell_w, graph["ell_rnbr"],
                 graph["ell_rslot"], graph["remote_dst"],
                 graph["remote_src_p2p"], graph["remote_w"], token)
 
@@ -1055,8 +1095,8 @@ def _make_aggregate_emulated(graph: dict, meta: DistMeta, policy: CommPolicy,
             out = out.at[rd].add(rw[:, None] * halo[rs])
             return out[:p_sz]
 
-        return jax.vmap(part, (0, 0, 0, 0, 0, 0, 0))(
-            x, graph["local_dst"], graph["local_src"], local_w,
+        return _per_part(
+            part, x, graph["local_dst"], graph["local_src"], local_w,
             graph["remote_dst"], graph["remote_src"], graph["remote_w"])
 
     def aggregate(li, x):
@@ -1520,7 +1560,7 @@ def make_train_step(cfg: GNNConfig, policy: CommPolicy, opt: Optimizer,
     def make_sm(packed_k: dict | None):
         return jax.jit(shard_map(make_worker(packed_k), mesh=mesh,
                                  in_specs=(P(), P(), P(AXIS), P(), P()),
-                                 out_specs=(P(), P(), P()), check_rep=False))
+                                 out_specs=(P(), P(), P()), check_vma=False))
 
     if needs_kb:
         # bounded: an annealing schedule walks many kept-block maps; keep
@@ -1596,7 +1636,7 @@ def make_eval_step(cfg: GNNConfig, meta: DistMeta, mesh: Mesh | None = None):
         return _accs(logits, gblk, reduce_psum=True)
 
     sm = shard_map(worker, mesh=mesh, in_specs=(P(), P(AXIS)),
-                   out_specs=P(), check_rep=False)
+                   out_specs=P(), check_vma=False)
     return jax.jit(sm)
 
 
@@ -1668,20 +1708,12 @@ def make_infer_step(cfg: GNNConfig, policy: CommPolicy, meta: DistMeta,
         return logits, tuple(hidden), bits, tuple(cache_out)
 
     def infer(params, graph, key, plan, cache=()):
-        rm = np.asarray(plan.rates, np.float32)
-        kb = _packed_pair_k_for(meta, rm)
-        wm = ww = None
-        if plan.widths is not None:
-            wm = np.vectorize(_snap_width)(
-                np.asarray(plan.widths, np.float32)).astype(np.float32)
-            ww = _packed_pair_w_for(meta, wm)
-        if not ww:
-            wm, ww = None, ()
+        rm, kb, wm, ww, sw = plan_statics(meta, plan)
         logits, hidden, bits, cache_new = _jit_infer(
             params, graph, key, jnp.asarray(rm),
             jnp.zeros((), jnp.float32) if wm is None else jnp.asarray(wm),
             jnp.asarray(plan.skip, jnp.float32), tuple(cache),
-            packed_k=kb, wire_w=ww, store_w=_packed_store_w(meta, wm))
+            packed_k=kb, wire_w=ww, store_w=sw)
         n_layers = 1 if rm.ndim == 2 else rm.shape[0]
         q2, lq2 = q * q, (1 if rm.ndim == 2 else rm.shape[0]) * q * q
         layer_t = bits[2:2 + lq2].reshape(n_layers, q, q)

@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs.base import ArchConfig
@@ -95,7 +95,7 @@ def make_varco_dp_train_step(cfg: ArchConfig, optimizer: Optimizer,
 
     sm = shard_map(worker, mesh=mesh,
                    in_specs=(P(), P(), P(AXIS), P(), P()),
-                   out_specs=(P(), P(), P()), check_rep=False)
+                   out_specs=(P(), P(), P()), check_vma=False)
 
     @jax.jit
     def step(params, opt_state, batch, step_idx, key):

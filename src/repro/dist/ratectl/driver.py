@@ -39,15 +39,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.varco import CommPolicy
 from repro.dist.gnn_parallel import (AXIS, COMPILED_CACHE_SIZE, DistMeta,
                                      _local_loss_fn, _make_aggregate_emulated,
-                                     _make_aggregate_shard, _packed_pair_k_for,
-                                     _packed_pair_w_for, _packed_store_w,
-                                     _pmean_inexact, _snap_width)
+                                     _make_aggregate_shard, _pmean_inexact,
+                                     plan_statics)
 from repro.dist.ratectl.base import RateController, RatePlan, make_pacing
 from repro.dist.ratectl.budget import budget_controller
 from repro.dist.ratectl.error import error_controller
@@ -280,19 +279,6 @@ def make_auto_train_step(cfg: GNNConfig, policy: CommPolicy, opt: Optimizer,
     # stale run at max_width < 32 quantises without EF (DESIGN.md §3.8)
     use_ef = policy.max_width < 32 and meta.wire == "p2p" and not stale
 
-    def _plan_widths(plan: RatePlan):
-        """Host-side width quantisation: snap the planned widths to the
-        supported storage grid (`_snap_width`, mirroring the kept-block
-        floor), and derive the jit-static distinct-width tuple
-        (`_packed_pair_w_for`) — ``()`` compiles the exact pre-
-        quantisation program.  Returns ``(wm | None, wire_w)``."""
-        if plan.widths is None:
-            return None, ()
-        wm = np.asarray(plan.widths, np.float32)
-        wm = np.vectorize(_snap_width)(wm).astype(np.float32)
-        ww = _packed_pair_w_for(meta, wm)
-        return (wm, ww) if ww else (None, ())
-
     if mesh is None:
         @functools.partial(jax.jit,
                            static_argnames=("packed_k", "wire_w", "store_w"))
@@ -329,16 +315,14 @@ def make_auto_train_step(cfg: GNNConfig, policy: CommPolicy, opt: Optimizer,
                     cache_new)
 
         def step(params, opt_state, graph, key, plan: RatePlan, cache=()):
-            rm = np.asarray(plan.rates, np.float32)
-            kb = _packed_pair_k_for(meta, rm)
-            wm, ww = _plan_widths(plan)
+            rm, kb, wm, ww, sw = plan_statics(meta, plan)
             out = _jit_step(params, opt_state, graph, key,
                             jnp.asarray(rm),
                             jnp.zeros((), jnp.float32) if wm is None
                             else jnp.asarray(wm),
                             jnp.asarray(plan.skip, jnp.float32),
                             tuple(cache), packed_k=kb, wire_w=ww,
-                            store_w=_packed_store_w(meta, wm))
+                            store_w=sw)
             # an exact (unquantised) step neither reads nor rewrites EF
             # residuals — carry them unchanged instead of dropping them
             return out if out[3] or not cache else (*out[:3], tuple(cache))
@@ -391,15 +375,12 @@ def make_auto_train_step(cfg: GNNConfig, policy: CommPolicy, opt: Optimizer,
         return jax.jit(shard_map(
             make_worker(kblocks, wire_w, ef, store_w), mesh=mesh,
             in_specs=(P(), P(), P(AXIS), P(), P(), P(), P(AXIS)),
-            out_specs=(P(), P(), P(), P(AXIS)), check_rep=False))
+            out_specs=(P(), P(), P(), P(AXIS)), check_vma=False))
 
     def step(params, opt_state, graph, key, plan: RatePlan, cache=()):
-        rm = np.asarray(plan.rates, np.float32)
-        kb = _packed_pair_k_for(meta, rm)
-        wm, ww = _plan_widths(plan)
+        rm, kb, wm, ww, sw = plan_statics(meta, plan)
         ef = use_ef and bool(ww) and bool(cache)
-        params, opt_state, m, cache_new = _compiled_for(
-            kb, ww, ef, _packed_store_w(meta, wm))(
+        params, opt_state, m, cache_new = _compiled_for(kb, ww, ef, sw)(
             params, opt_state, graph, jnp.asarray(rm),
             jnp.zeros((), jnp.float32) if wm is None else jnp.asarray(wm),
             key, tuple(cache))

@@ -6,13 +6,18 @@ with degree-padded ELL neighbour lists).  CSR row-gather is replaced by a
 VMEM:
 
   grid (node_tiles, feature_blocks, source_chunks); the kernel holds a
-  ``[sc, bf]`` source-chunk slab of ``x`` in VMEM, gathers the neighbour
-  rows that fall inside the chunk (others masked), and accumulates the
-  weighted sum in a VMEM scratch, writing out on the last chunk.
+  ``[sc, bf]`` source-chunk slab of ``x`` in VMEM, builds the dense
+  ``[tn, sc]`` weight tile ``A[t, s] = Σ_k w[t,k]·[nbr[t,k] − lo == s]``
+  of the neighbours that fall inside the chunk (one iota compare per
+  ``k``, so no ``[tn, K, sc]`` intermediate ever exists), and accumulates
+  ``A @ x_chunk`` on the MXU in a VMEM scratch, writing out on the last
+  chunk.  There is no data-dependent VMEM gather, which Mosaic cannot
+  lower; the price is work quadratic in the node count (every node tile
+  sweeps every source chunk).
 
-Every neighbour gather is VMEM-local; HBM traffic is one pass over ``x``
-per node tile.  Validated against ``ref.ell_spmm_reference`` in interpret
-mode over shape sweeps incl. ragged/padded degrees.
+HBM traffic is one pass over ``x`` per node tile.  Validated against
+``ref.ell_spmm_reference`` in interpret mode over shape sweeps incl.
+ragged/padded degrees.
 
 The kernel itself is forward-only; the runtime's differentiable entry
 point is :func:`repro.kernels.ops.ell_aggregate`, whose custom VJP runs
@@ -41,17 +46,16 @@ def _ell_kernel(nbr_ref, w_ref, x_ref, out_ref, acc_scr, *,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     nbr = nbr_ref[...]                       # [tn, K] int32 (global ids)
-    w = w_ref[...]                           # [tn, K] f32
-    x = x_ref[...]                           # [sc, bf] source chunk slab
+    w = w_ref[...].astype(jnp.float32)       # [tn, K]
+    x = x_ref[...].astype(jnp.float32)       # [sc, bf] source chunk slab
 
-    lo = ci * sc
-    local = nbr - lo
-    in_chunk = (local >= 0) & (local < sc)
-    safe = jnp.where(in_chunk, local, 0)
-    gathered = x[safe]                       # [tn, K, bf] VMEM gather
-    wm = jnp.where(in_chunk, w, 0.0).astype(jnp.float32)
-    acc_scr[...] += jnp.einsum("tk,tkf->tf", wm,
-                               gathered.astype(jnp.float32))
+    local = nbr - ci * sc                    # out-of-chunk ids match no s
+    s_ids = jax.lax.broadcasted_iota(jnp.int32, (nbr.shape[0], sc), 1)
+    a = jnp.zeros((nbr.shape[0], sc), jnp.float32)
+    for k in range(nbr.shape[1]):            # static: K is a block dim
+        a = a + jnp.where(s_ids == local[:, k:k + 1], w[:, k:k + 1], 0.0)
+    acc_scr[...] += jnp.dot(a, x, precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)
 
     @pl.when(ci == n_chunks - 1)
     def _finalize():

@@ -71,50 +71,82 @@ def varco_pack(x: jax.Array, block_idx: jax.Array, *, tile_n: int = 256,
     )(block_idx, x)
 
 
-def _bitpack_block(levels: jax.Array, width: int) -> jax.Array:
-    """int8 levels [tn, LANE] -> packed uint8 [tn, LANE*width/8].
+def _lane_maps(m: int, width: int):
+    """Lane → byte maps of the sub-byte layout for ``m`` lanes: each lane's
+    byte column ``lane // vpb`` and bit offset ``(lane % vpb) * width``
+    (``vpb = 8 / width``), as ``[1, m]`` int32 rows (shifts and masks:
+    ``vpb`` is a power of two, and Mosaic has no vector integer divide)."""
+    vpb = 8 // width
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, m), 1)
+    return lane >> (vpb.bit_length() - 1), (lane & (vpb - 1)) * width
 
-    One byte holds ``vpb = 8/width`` consecutive lanes, little-endian
-    within the byte (lane ``c*vpb + j`` at bit offset ``j*width``).  The
-    strided slice ``levels[:, j::vpb]`` is exactly the offset-``j`` lane
-    of every group, so the combine is ``vpb`` shifted ORs — no in-kernel
-    reshape.  Shared by the pack and the oracle-checked byte layout
-    (:func:`repro.kernels.ref.pack_bits_reference`).
+
+def _bitpack_fields(levels: jax.Array, width: int, col0=0,
+                    n_cols: int | None = None) -> jax.Array:
+    """int levels ``[tn, m]`` -> byte values ``[tn, n_cols]`` f32, lane
+    ``i``'s low ``width`` bits landing in byte column ``col0 + i // vpb``
+    at bit offset ``(i % vpb) * width`` (``col0`` may be traced).
+
+    The lane → byte compaction is a matmul against the placement matrix
+    ``P[i, c] = 2^(bit offset of i)·[c == col0 + i // vpb]``: byte values
+    stay below 256 and ``P`` holds powers of two, so the product is exact
+    at any MXU precision.  (Mosaic lowers no strided lane slice, so the
+    compaction cannot be a slice-and-OR.)
     """
-    lv = levels.astype(jnp.int8)
-    if width == 8:
-        return jax.lax.bitcast_convert_type(lv, jnp.uint8)
-    vpb = 8 // width
-    u = jax.lax.bitcast_convert_type(lv, jnp.uint8) & jnp.uint8(2 ** width - 1)
-    out = u[:, 0::vpb]
-    for j in range(1, vpb):
-        out = out | (u[:, j::vpb] << jnp.uint8(j * width))
-    return out
+    m = levels.shape[-1]
+    n_cols = m * width // 8 if n_cols is None else n_cols
+    col, shift = _lane_maps(m, width)
+    u = levels.astype(jnp.int32) & (2 ** width - 1)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (m, n_cols), 1)
+    place = jnp.where(cols == col.reshape(m, 1) + col0,
+                      (1 << shift).reshape(m, 1).astype(jnp.float32), 0.0)
+    return jnp.dot(u.astype(jnp.float32), place,
+                   precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
 
 
-def _bitunpack_block(packed: jax.Array, width: int) -> jax.Array:
-    """Inverse of :func:`_bitpack_block`: uint8 [tn, LANE*width/8] ->
-    sign-extended int8 levels [tn, LANE] via interleaved strided sets."""
-    if width == 8:
-        return jax.lax.bitcast_convert_type(packed, jnp.int8)
-    vpb = 8 // width
-    mask = jnp.uint8(2 ** width - 1)
-    out = jnp.zeros((packed.shape[0], packed.shape[1] * vpb), jnp.int32)
-    for j in range(vpb):
-        v = ((packed >> jnp.uint8(j * width)) & mask).astype(jnp.int32)
-        v = jnp.where(v >= 2 ** (width - 1), v - 2 ** width, v)
-        out = out.at[:, j::vpb].set(v)
-    return out.astype(jnp.int8)
+def _bitunpack_fields(packed: jax.Array, width: int, col0=0,
+                      m: int | None = None) -> jax.Array:
+    """Inverse of :func:`_bitpack_fields`: bytes ``[tn, B]`` -> the ``m``
+    sign-extended int32 levels stored from byte column ``col0`` on
+    (``col0`` may be traced).  Each lane's byte is fetched by a one-hot
+    matmul (exact: byte values below 256), then shifted and masked."""
+    m = packed.shape[-1] * 8 // width if m is None else m
+    col, shift = _lane_maps(m, width)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (packed.shape[-1], m), 0)
+    pick = jnp.where(rows == col + col0, 1.0, 0.0)
+    byte = jnp.dot(packed.astype(jnp.int32).astype(jnp.float32), pick,
+                   precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32).astype(jnp.int32)
+    v = (byte >> shift) & (2 ** width - 1)
+    return jnp.where(v >= 2 ** (width - 1), v - 2 ** width, v)
 
 
-def _pack_quant_kernel(idx_ref, x_ref, out_ref, scale_ref, *, qmax, width):
+def _pack_quant_kernel(idx_ref, x_ref, out_ref, scale_ref, bytes_scr,
+                       scale_scr, *, qmax, width, bpb):
     del idx_ref  # consumed by the index_map
-    xb = x_ref[...]
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        bytes_scr[...] = jnp.zeros_like(bytes_scr)
+        scale_scr[...] = jnp.zeros_like(scale_scr)
+
+    xb = x_ref[...].astype(jnp.float32)
     amax = jnp.max(jnp.abs(xb), axis=-1, keepdims=True)
     scale = jnp.where(amax > 0, amax / qmax, 1.0)
-    levels = jnp.clip(jnp.rint(xb / scale), -qmax, qmax).astype(jnp.int8)
-    out_ref[...] = _bitpack_block(levels, width)
-    scale_ref[...] = scale
+    levels = jnp.clip(jnp.rint(xb / scale), -qmax, qmax)
+    # kept block j owns byte columns [j*bpb, (j+1)*bpb) and scale column
+    # j of the row tile's resident outputs
+    bytes_scr[...] += _bitpack_fields(levels, width, col0=j * bpb,
+                                      n_cols=bytes_scr.shape[-1])
+    kcol = jax.lax.broadcasted_iota(jnp.int32, scale_scr.shape, 1)
+    scale_scr[...] += jnp.where(kcol == j, scale, 0.0)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _store():
+        out_ref[...] = bytes_scr[...].astype(jnp.int32).astype(jnp.uint8)
+        scale_ref[...] = scale_scr[...]
 
 
 def varco_pack_quant(x: jax.Array, block_idx: jax.Array, *, width: int,
@@ -127,11 +159,13 @@ def varco_pack_quant(x: jax.Array, block_idx: jax.Array, *, width: int,
     exactly as in :func:`varco_pack`, and *in the same tile visit* the
     kernel computes the per-row block amax, the symmetric scale
     ``amax / qmax`` with ``qmax = 2^(width-1) - 1``, the rounded-clipped
-    int8 levels, AND the sub-byte bit-pack (``8/width`` lanes per byte,
+    levels, AND the sub-byte bit-pack (``8/width`` lanes per byte,
     little-endian — ``width == 8`` stores bitwise the int8 lanes the
-    pre-packing wire shipped) — the fp32 intermediate and the one-lane-
-    per-byte int8 buffer never exist.  ``width`` ∈ {2, 4, 8}; storage
-    now matches the ledger's ``LANE·width`` payload charge exactly.
+    pre-packing wire shipped) — the fp32 intermediate never reaches HBM.
+    The payload row and the scales of a row tile stay resident in VMEM
+    across the kept blocks (full-width blocks meet the (8, 128) tiling
+    rule at any ``K``) and are stored once.  ``width`` ∈ {2, 4, 8};
+    storage matches the ledger's ``LANE·width`` payload charge exactly.
     Oracle: :func:`repro.kernels.ref.pack_quant_reference`; decode with
     :func:`varco_unpack_quant` / ``ref.unpack_quant_reference``.
     """
@@ -151,12 +185,15 @@ def varco_pack_quant(x: jax.Array, block_idx: jax.Array, *, width: int,
             pl.BlockSpec((tn, LANE), lambda i, j, idx: (i, idx[j])),
         ],
         out_specs=[
-            pl.BlockSpec((tn, bpb), lambda i, j, idx: (i, j)),
-            pl.BlockSpec((tn, 1), lambda i, j, idx: (i, j)),
+            pl.BlockSpec((tn, k * bpb), lambda i, j, idx: (i, 0)),
+            pl.BlockSpec((tn, k), lambda i, j, idx: (i, 0)),
         ],
+        scratch_shapes=[pltpu.VMEM((tn, k * bpb), jnp.float32),
+                        pltpu.VMEM((tn, k), jnp.float32)],
     )
     return pl.pallas_call(
-        functools.partial(_pack_quant_kernel, qmax=qmax, width=width),
+        functools.partial(_pack_quant_kernel, qmax=qmax, width=width,
+                          bpb=bpb),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((n, k * bpb), jnp.uint8),
                    jax.ShapeDtypeStruct((n, k), jnp.float32)],
@@ -164,16 +201,22 @@ def varco_pack_quant(x: jax.Array, block_idx: jax.Array, *, width: int,
     )(block_idx, x)
 
 
-def _unpack_quant_kernel(inv_ref, packed_ref, scale_ref, out_ref, *, width):
+def _unpack_quant_kernel(inv_ref, packed_ref, scale_ref, out_ref, *, width,
+                         bpb):
     j = pl.program_id(1)
-    live = inv_ref[j] >= 0
+    p = inv_ref[j]
 
-    @pl.when(live)
+    @pl.when(p >= 0)
     def _decode():
-        levels = _bitunpack_block(packed_ref[...], width)
-        out_ref[...] = levels.astype(jnp.float32) * scale_ref[...]
+        levels = _bitunpack_fields(packed_ref[...], width, col0=p * bpb,
+                                   m=LANE)
+        sc = scale_ref[...]
+        kcol = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+        scale = jnp.sum(jnp.where(kcol == p, sc, 0.0), axis=-1,
+                        keepdims=True)
+        out_ref[...] = levels.astype(jnp.float32) * scale
 
-    @pl.when(jnp.logical_not(live))
+    @pl.when(p < 0)
     def _zero():
         out_ref[...] = jnp.zeros_like(out_ref)
 
@@ -185,16 +228,19 @@ def varco_unpack_quant(payload: jax.Array, scales: jax.Array,
 
     payload uint8 [N, K*128*width/8], scales f32 [N, K], inv_idx [F/128]
     (packed block column of each output block, -1 if dropped) -> f32
-    [N, F].  One launch does what unpack-then-dequant did in two: each
-    live output block DMA-routes its payload bytes and scale column into
-    VMEM, sign-extends the ``width``-bit fields and multiplies by the
-    block scale; dropped blocks are zero-filled (the paper's decoder).
-    Oracle: ``ref.unpack_quant_reference`` + ``ref.unpack_reference``.
+    [N, F].  One launch does what unpack-then-dequant did in two: a row
+    tile's whole payload row and scales sit in VMEM (full-width blocks,
+    per the (8, 128) tiling rule), each live output block decodes its
+    packed column — sign-extends the ``width``-bit fields and multiplies
+    by the block scale — and dropped blocks are zero-filled (the paper's
+    decoder).  Oracle: ``ref.unpack_quant_reference`` +
+    ``ref.unpack_reference``.
     """
     n, kb = payload.shape
     assert width in (2, 4, 8), width
     bpb = LANE * width // 8
     assert kb % bpb == 0, (kb, bpb)
+    k = scales.shape[-1]
     nf = inv_idx.shape[0]
     tn = min(tile_n, n)
     assert n % tn == 0, (n, tn)
@@ -203,15 +249,13 @@ def varco_unpack_quant(payload: jax.Array, scales: jax.Array,
         num_scalar_prefetch=1,
         grid=(n // tn, nf),
         in_specs=[
-            pl.BlockSpec((tn, bpb),
-                         lambda i, j, inv: (i, jnp.maximum(inv[j], 0))),
-            pl.BlockSpec((tn, 1),
-                         lambda i, j, inv: (i, jnp.maximum(inv[j], 0))),
+            pl.BlockSpec((tn, kb), lambda i, j, inv: (i, 0)),
+            pl.BlockSpec((tn, k), lambda i, j, inv: (i, 0)),
         ],
         out_specs=pl.BlockSpec((tn, LANE), lambda i, j, inv: (i, j)),
     )
     return pl.pallas_call(
-        functools.partial(_unpack_quant_kernel, width=width),
+        functools.partial(_unpack_quant_kernel, width=width, bpb=bpb),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, nf * LANE), jnp.float32),
         interpret=interpret,

@@ -130,7 +130,7 @@ FORWARD_SCRIPT = r"""
 import json, sys
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from parity import build_setup
 from repro.core import CommPolicy
 from repro.dist.gnn_parallel import (DistMeta, _make_aggregate_emulated,
@@ -205,7 +205,7 @@ for case in spec["cases"]:
                                          P(), P(), P("workers"), P()),
                                out_specs=(P("workers"), P(),
                                           P("workers")),
-                               check_rep=False))
+                               check_vma=False))
         rcache = tuple(jnp.asarray(_cache_send_to_recv(np.asarray(c), q))
                        for c in fcache)
         ls, bs, fo_s = sm(params, gs, jnp.asarray(rm),
@@ -244,7 +244,7 @@ for case in spec["cases"]:
             sm = jax.jit(shard_map(worker, mesh=mesh,
                                    in_specs=(P(), P("workers"), P(), P()),
                                    out_specs=(P("workers"), P()),
-                                   check_rep=False))
+                                   check_vma=False))
             ls, bs = sm(params, gs, jnp.asarray(rm), key)
         else:
             def worker(p, gblk, rmap, wmap, k):
@@ -258,7 +258,7 @@ for case in spec["cases"]:
                                    in_specs=(P(), P("workers"), P(), P(),
                                              P()),
                                    out_specs=(P("workers"), P()),
-                                   check_rep=False))
+                                   check_vma=False))
             ls, bs = sm(params, gs, jnp.asarray(rm), jnp.asarray(wm), key)
     else:
         rate = float(pol.rate(0)) if pol.compresses else 1.0
@@ -281,7 +281,7 @@ for case in spec["cases"]:
         sm = jax.jit(shard_map(worker, mesh=mesh,
                                in_specs=(P(), P("workers"), P(), P()),
                                out_specs=(P("workers"), P()),
-                               check_rep=False))
+                               check_vma=False))
         ls, bs = sm(params, gs, jnp.asarray(rate), key)
     le, be = gnn_forward(params, cfg, graph["features"], agg_e)
     dl = float(jnp.abs(le - ls).max())
@@ -296,7 +296,7 @@ CONSERVE_SCRIPT = r"""
 import json, math, sys
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from parity import build_setup
 from repro.core import CommPolicy
 from repro.dist.gnn_parallel import (DistMeta, _make_aggregate_emulated,
@@ -357,7 +357,7 @@ for w in spec["widths"]:
     sm = jax.jit(shard_map(worker, mesh=mesh,
                            in_specs=(P(), P("workers"), P(), P(), P()),
                            out_specs=(P("workers"), P(), P("workers")),
-                           check_rep=False))
+                           check_vma=False))
     ls, bs, ws = sm(params, gs, jnp.asarray(rm), jnp.asarray(wm), key)
     assert len(ws) == layers * D, (w, len(ws))
 
@@ -542,7 +542,7 @@ def _run(script: str, spec: dict, q: int, sentinel: str,
          timeout: int = 1200) -> str:
     # tests/ on the path so the scripts import parity.build_setup — ONE
     # scaffold construction, in-process and in the subprocess
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS=f"--xla_force_host_platform_device_count={q}",
                PYTHONPATH=os.pathsep.join(
                    [SRC, os.path.dirname(os.path.abspath(__file__))]))

@@ -61,7 +61,7 @@ def test_real_hlo_scan_multiplier():
     """End-to-end: a jitted scan with a psum-like collective is scaled."""
     import functools
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     # single-device "mesh" won't emit collectives; instead check scan body
     # counting with a dot inside a loop via collective-free sanity: the
     # multiplier machinery must find trip count 7 for a length-7 scan.

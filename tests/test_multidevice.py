@@ -15,7 +15,7 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
 def run_py(code: str, timeout: int = 600) -> str:
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=8",
                PYTHONPATH=SRC)
     out = subprocess.run([sys.executable, "-c", code], env=env,
@@ -124,7 +124,7 @@ COLLECTIVES = """
 import functools
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from repro.core.collectives import (compressed_all_gather, compressed_psum,
                                     compressed_all_to_all, uncompressed_bits)
 from repro.core.compression import get_compressor
@@ -134,7 +134,7 @@ c = get_compressor('randmask')
 x = jax.random.normal(jax.random.key(0), (4, 8, 16))
 
 @functools.partial(shard_map, mesh=mesh, in_specs=P('w'), out_specs=(P('w'), P()),
-                   check_rep=False)
+                   check_vma=False)
 def gather_rate1(xs):
     g, bits = compressed_all_gather(xs[0], 'w', compressor=c,
                                     rate=jnp.float32(1.0),
@@ -148,7 +148,7 @@ assert float(bits) == 3 * x.size / 4 * 32 * 4 / 4 * 4 / 4 or True
 assert float(bits) == 4 * 8 * 16 * 32 * 3, float(bits)
 
 @functools.partial(shard_map, mesh=mesh, in_specs=P('w'), out_specs=(P('w'), P()),
-                   check_rep=False)
+                   check_vma=False)
 def psum_rate1(xs):
     s, bits = compressed_psum(xs[0], 'w', compressor=c,
                               rate=jnp.float32(1.0), key=jax.random.key(1))
@@ -158,7 +158,7 @@ s, bits = psum_rate1(x)
 np.testing.assert_allclose(np.asarray(s[0]), np.asarray(x.sum(0)), rtol=1e-5)
 
 @functools.partial(shard_map, mesh=mesh, in_specs=P('w'), out_specs=(P('w'), P()),
-                   check_rep=False)
+                   check_vma=False)
 def a2a_rate1(xs):
     o, bits = compressed_all_to_all(xs[0], 'w', compressor=c,
                                     rate=jnp.float32(1.0),

@@ -367,7 +367,7 @@ print('PACKED_SHARD_OK')
 
 @pytest.mark.slow
 def test_packed_shard_map_matches_emulated():
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=8",
                PYTHONPATH=SRC)
     out = subprocess.run([sys.executable, "-c", PACKED_SHARD_EQUIV], env=env,

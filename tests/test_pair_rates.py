@@ -225,11 +225,11 @@ def test_neighbor_exchange_pair_k_needs_n_keep():
             return neighbor_exchange(x, jnp.zeros((1, 2), jnp.int32),
                                      jnp.ones((1, 2)), "w",
                                      pair_k=jnp.ones((2, 2), jnp.int32))[0]
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         mesh = Mesh(np.asarray(jax.devices()[:1]), ("w",))
         return jax.jit(shard_map(worker, mesh=mesh, in_specs=P("w"),
-                                 out_specs=P("w"), check_rep=False))(
+                                 out_specs=P("w"), check_vma=False))(
             jnp.zeros((1, 4, 256)))
 
     with pytest.raises(ValueError, match="n_keep"):

@@ -360,17 +360,18 @@ def test_bitpack_roundtrip_reference(width, n, m, seed):
 @given(width=st.sampled_from([2, 4, 8]), n=st.integers(1, 8),
        k=st.integers(1, 4), seed=st.integers(0, 2 ** 16))
 def test_bitpack_roundtrip_kernel_helpers(width, n, k, seed):
-    """The in-kernel strided-slice pack (`_bitpack_block`) is bitwise the
-    reference grouping, and `_bitunpack_block` inverts it — the fused
-    ``varco_pack_quant`` / ``varco_unpack_quant`` codec path."""
+    """The in-kernel placement-matmul pack (`_bitpack_fields`) yields
+    bitwise the reference bytes, and `_bitunpack_fields` inverts them —
+    the fused ``varco_pack_quant`` / ``varco_unpack_quant`` codec path."""
     from repro.kernels.ref import pack_bits_reference
-    from repro.kernels.varco_pack import _bitpack_block, _bitunpack_block
+    from repro.kernels.varco_pack import _bitpack_fields, _bitunpack_fields
 
     rng = np.random.default_rng(seed)
     lo, hi = -(2 ** (width - 1)), 2 ** (width - 1) - 1
     x = rng.integers(lo, hi + 1, size=(n, k * LANE)).astype(np.int8)
-    packed = np.asarray(_bitpack_block(jnp.asarray(x), width))
+    packed = np.asarray(_bitpack_fields(jnp.asarray(x), width))
     np.testing.assert_array_equal(
         packed, np.asarray(pack_bits_reference(jnp.asarray(x), width)))
-    un = np.asarray(_bitunpack_block(jnp.asarray(packed), width))
+    un = np.asarray(_bitunpack_fields(jnp.asarray(packed.astype(np.uint8)),
+                                      width))
     np.testing.assert_array_equal(un, x)

@@ -24,7 +24,7 @@ from repro.dist.ratectl import (RatePlan, budget_controller, error_controller,
                                 width_cost)
 from repro.kernels import ref
 from repro.kernels.ops import (LANE, pack_quant, per_block_wire_bits,
-                               quant_dequant)
+                               quant_dequant, unpack_quant)
 from repro.kernels.varco_pack import block_mask_indices
 from repro.nn.gnn import gnn_forward
 from repro.train.optim import sgd
@@ -104,6 +104,22 @@ def test_pack_quant_matches_reference(width):
     np.testing.assert_allclose(np.asarray(dq),
                                np.asarray(quant_dequant(payload, width)),
                                rtol=0, atol=1e-6)
+
+
+@pytest.mark.parametrize("width", WIDTHS)
+def test_unpack_quant_matches_reference(width):
+    """The fused decode kernel (interpret mode) rebuilds, bitwise, what
+    the oracles decode from the same bytes — dequantised kept blocks
+    scattered back, dropped blocks zero."""
+    key = jax.random.key(5)
+    x = jax.random.normal(key, (40, F), jnp.float32)
+    kept, inv = block_mask_indices(key, NB, 2.0)
+    payload, scales = ref.pack_quant_reference(x, kept, width)
+    got = unpack_quant(payload, scales, inv, width=width, interpret=True)
+    want = ref.unpack_reference(
+        ref.unpack_quant_reference(payload, scales, width), inv)
+    assert got.shape == (40, F) and got.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_per_block_wire_bits_values():
