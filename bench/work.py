@@ -1,0 +1,116 @@
+"""Work counts: the operations and bytes that the algorithm needs, from the
+graph's real rows and edges and the model's widths.
+
+Nothing here looks at how a kernel tiles its grid or pads its degrees, so
+the counts stay valid when a kernel is replaced.  A partition's "local"
+edges join two nodes it owns; the rest are "remote" and need a halo row.
+"""
+
+from __future__ import annotations
+
+import json
+from pathlib import Path
+
+import numpy as np
+
+PEAKS = json.loads((Path(__file__).parent / "peaks.json").read_text())
+
+
+def peak(device_kind: str) -> dict:
+    """The chip's published peaks; an unknown chip is an error."""
+    try:
+        return PEAKS["devices"][device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS['devices'])}")
+
+
+def partition_counts(indptr: np.ndarray, indices: np.ndarray,
+                     owner: np.ndarray, q: int) -> dict:
+    """Per partition: ``rows``, ``local_edges``, ``remote_edges`` (directed,
+    by destination), and ``halo_rows[i, j]``: rows partition ``i`` owns
+    that partition ``j`` needs."""
+    n = len(indptr) - 1
+    dst = np.repeat(np.arange(n), np.diff(indptr))
+    src = indices.astype(np.int64)
+    od, os_ = owner[dst], owner[src]
+    local = od == os_
+    rows = np.bincount(owner, minlength=q)
+    local_edges = np.bincount(od[local], minlength=q)
+    remote_edges = np.bincount(od[~local], minlength=q)
+    pairs = np.unique(src[~local] * q + od[~local])     # (row, receiver)
+    halo = np.zeros((q, q), np.int64)
+    np.add.at(halo, (owner[pairs // q], pairs % q), 1)
+    return {"rows": rows, "local_edges": local_edges,
+            "remote_edges": remote_edges, "halo_rows": halo,
+            "edges": len(indices), "nodes": n}
+
+
+def layer_widths(model: dict, in_dim: int, out_dim: int):
+    ds = [in_dim] + [model["hidden"]] * (model["num_layers"] - 1) + [out_dim]
+    return list(zip(ds[:-1], ds[1:]))
+
+
+def ell_calls(model: dict, in_dim: int, out_dim: int) -> list[int]:
+    """Feature width of each local aggregation in one training step: one
+    per layer forward, and its transpose in the backward for every layer
+    whose input depends on the weights (all but the first)."""
+    fwd = [d_in for d_in, _ in layer_widths(model, in_dim, out_dim)]
+    return fwd + fwd[1:]
+
+
+def ell_cost(f: int, rows: int, local_edges: int) -> tuple[float, float]:
+    """``(flops, bytes)`` of one partition's local aggregation at width
+    ``f``: a multiply-add per edge and feature; read every source row and
+    write every destination row once in float32, and read each edge's
+    int32 index and float32 weight."""
+    return 2.0 * local_edges * f, 4.0 * f * (rows + rows) + 8.0 * local_edges
+
+
+def least_seconds(flops: float, nbytes: float, pk: dict) -> tuple[float, str]:
+    """The least time the chip could take, and which peak bounds it."""
+    t_c = flops / pk["flops_per_s"]
+    t_m = nbytes / pk["hbm_bytes_per_s"]
+    return (t_c, "compute") if t_c >= t_m else (t_m, "memory")
+
+
+def ell_least_per_epoch(counts: dict, model: dict, in_dim: int,
+                        out_dim: int, pk: dict) -> tuple[float, str]:
+    """Least seconds of one training step's local aggregations, summed
+    over calls and partitions (each partition is its own launch)."""
+    total, bounds = 0.0, set()
+    for f in ell_calls(model, in_dim, out_dim):
+        for rows, loc in zip(counts["rows"], counts["local_edges"]):
+            t, b = least_seconds(*ell_cost(f, int(rows), int(loc)), pk)
+            total += t
+            bounds.add(b)
+    return total, "+".join(sorted(bounds))
+
+
+def forward_flops(model: dict, n: int, e: int, in_dim: int,
+                  out_dim: int) -> float:
+    """One forward pass over the whole graph: per layer two dense
+    products ``2 N d_in d_out`` (self and neighbour) and the aggregation
+    ``2 E d_in``."""
+    return sum(4.0 * n * a * b + 2.0 * e * a
+               for a, b in layer_widths(model, in_dim, out_dim))
+
+
+def train_step_flops(model: dict, n: int, e: int, in_dim: int,
+                     out_dim: int) -> float:
+    """Forward and backward of one full-graph training step.  The
+    backward takes the weight gradients of every layer (``2 N d_in d_out``
+    per product) and the input gradients of all but the first layer
+    (another two products and the transposed aggregation)."""
+    dims = layer_widths(model, in_dim, out_dim)
+    bwd = sum(4.0 * n * a * b for a, b in dims)
+    bwd += sum(4.0 * n * a * b + 2.0 * e * a for a, b in dims[1:])
+    return forward_flops(model, n, e, in_dim, out_dim) + bwd
+
+
+def epoch_flops(model: dict, n: int, e: int, in_dim: int, out_dim: int,
+                eval_every: int) -> float:
+    """A training epoch with its share of the periodic evaluation's
+    forward pass."""
+    return (train_step_flops(model, n, e, in_dim, out_dim)
+            + forward_flops(model, n, e, in_dim, out_dim) / eval_every)
