@@ -1,0 +1,15 @@
+"""Device time of the local aggregation per epoch, averaged over chips:
+the train step's ops under the program's ``local_agg`` scope (the ELL
+kernel with its glue, forward and transposed, on the p2p wire), read by
+``spans.py``.  Unlike ``ell_spmm_ms`` it does not depend on the kernel's
+operands.  Moves ``epoch_s``.
+"""
+
+import spans
+
+UNIT = "ms/epoch"
+
+
+def read(run):
+    s = spans.for_run(run)
+    return None if s is None else s.ms_per_epoch("local_agg", run.epochs)

@@ -28,6 +28,14 @@ def enable_compile_cache() -> str:
     goes to :data:`DEFAULT_CACHE_DIR` — never a temporary, per-process or
     time-stamped path, which would never be hit again.
     """
+    # An executable keeps the HLO metadata of the program that compiled
+    # it, and JAX leaves that metadata out of the key by default: a
+    # program that differs from a cached one only in its layer names
+    # (``repro.tracing``) would run, and profile, under the old names.
+    # Key on the metadata, with each op's location cut to its name so that
+    # the key holds no file path or line.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_traceback_in_locations_limit", 0)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

@@ -356,7 +356,6 @@ def make_fault_train_step(cfg, policy, opt, meta, mesh=None, sync: str = "grad",
 
     import jax
     import jax.numpy as jnp
-    from jax import lax
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
@@ -364,7 +363,8 @@ def make_fault_train_step(cfg, policy, opt, meta, mesh=None, sync: str = "grad",
                                          _local_loss_fn,
                                          _make_aggregate_emulated,
                                          _make_aggregate_shard,
-                                         _pmean_inexact, plan_statics)
+                                         _pmean_inexact, _psum_tree,
+                                         plan_statics)
     from repro.dist.ratectl.driver import _auto_metrics, exchange_widths
     from repro.kernels.varco_pack import LANE
     from repro.nn.gnn import gnn_forward, masked_loss_and_correct
@@ -484,10 +484,9 @@ def make_fault_train_step(cfg, policy, opt, meta, mesh=None, sync: str = "grad",
 
             (loss, (bits, fcache_new)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params)
-            loss = lax.psum(loss, AXIS)
+            loss = _psum_tree(loss, AXIS)
             if sync == "grad":
-                grads = jax.tree_util.tree_map(lambda g: lax.psum(g, AXIS),
-                                               grads)
+                grads = _psum_tree(grads, AXIS)
                 updates, new_state = opt.update(grads, opt_state, params)
                 params = apply_updates(params, updates)
             else:  # fedavg

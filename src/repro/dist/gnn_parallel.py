@@ -803,6 +803,7 @@ def _make_aggregate_emulated(graph: dict, meta: DistMeta, policy: CommPolicy,
         dropped = pos_all[:, None, :] >= k_used[:, :, None]  # [Q, D, nb]
         return _scatter_pairs(jnp.sum(energy * dropped, -1), q)
 
+    @jax.named_scope("halo")
     def start(li, x):                                  # x: [Q, P, F]
         """Issue layer ``li``'s exchange: pack, mask, ship.  Returns
         ``(halo token, bits)`` — the token is consumed by :func:`complete`
@@ -1059,26 +1060,31 @@ def _make_aggregate_emulated(graph: dict, meta: DistMeta, policy: CommPolicy,
         del li
         f = x.shape[-1]
         if not policy.communicates:                    # No-Comm baseline
-            return _per_part(lambda xq, ld, ls, w:
-                             jnp.zeros((p_sz + 1, f), x.dtype)
-                             .at[ld].add(w[:, None] * xq[ls])[:p_sz],
-                x, graph["local_dst"], graph["local_src"],
-                graph["local_w_iso"])
+            with jax.named_scope("local_agg"):
+                return _per_part(lambda xq, ld, ls, w:
+                                 jnp.zeros((p_sz + 1, f), x.dtype)
+                                 .at[ld].add(w[:, None] * xq[ls])[:p_sz],
+                                 x, graph["local_dst"], graph["local_src"],
+                                 graph["local_w_iso"])
 
         if p2p_wire:
-            ell_w = _ell_w_for(graph, policy, rate)
-            if dead is not None:
-                # local-only fallback: blend each receiver's aggregation
-                # weights toward the isolated normalisation by its dark
-                # remote-row fraction (all pairs dead → exact No-Comm)
-                mix = _dead_mix(meta, dead)
-                ell_w = ell_w + mix[:, None, None] * \
-                    (graph["ell_w_iso"] - ell_w)
+            with jax.named_scope("local_agg"):
+                ell_w = _ell_w_for(graph, policy, rate)
+                if dead is not None:
+                    # local-only fallback: blend each receiver's
+                    # aggregation weights toward the isolated normalisation
+                    # by its dark remote-row fraction (all pairs dead →
+                    # exact No-Comm)
+                    mix = _dead_mix(meta, dead)
+                    ell_w = ell_w + mix[:, None, None] * \
+                        (graph["ell_w_iso"] - ell_w)
 
             def part_p2p(xq, nbr, w, rnbr, rslot, rd, rs, rw, halo_c):
-                loc = ell_aggregate(xq, nbr, w, rnbr, rslot)
-                rem = jnp.zeros((p_sz + 1, f), x.dtype)
-                rem = rem.at[rd].add(rw[:, None] * halo_c[rs])
+                with jax.named_scope("local_agg"):
+                    loc = ell_aggregate(xq, nbr, w, rnbr, rslot)
+                with jax.named_scope("remote_agg"):
+                    rem = jnp.zeros((p_sz + 1, f), x.dtype)
+                    rem = rem.at[rd].add(rw[:, None] * halo_c[rs])
                 return loc + rem[:p_sz]
 
             return _per_part(
@@ -1087,12 +1093,15 @@ def _make_aggregate_emulated(graph: dict, meta: DistMeta, policy: CommPolicy,
                 graph["remote_src_p2p"], graph["remote_w"], token)
 
         halo = token
-        local_w = _local_w_for(graph, policy, rate)
+        with jax.named_scope("local_agg"):
+            local_w = _local_w_for(graph, policy, rate)
 
         def part(xq, ld, ls, lw, rd, rs, rw):
-            out = jnp.zeros((p_sz + 1, f), x.dtype)
-            out = out.at[ld].add(lw[:, None] * xq[ls])
-            out = out.at[rd].add(rw[:, None] * halo[rs])
+            with jax.named_scope("local_agg"):
+                out = jnp.zeros((p_sz + 1, f), x.dtype)
+                out = out.at[ld].add(lw[:, None] * xq[ls])
+            with jax.named_scope("remote_agg"):
+                out = out.at[rd].add(rw[:, None] * halo[rs])
             return out[:p_sz]
 
         return _per_part(
@@ -1225,6 +1234,7 @@ def _make_aggregate_shard(graph: dict, meta: DistMeta, policy: CommPolicy,
         err_d = jnp.sum(energy * dropped, -1)                  # [D]
         return _scatter_pairs(lax.all_gather(err_d, axis), q)
 
+    @jax.named_scope("halo")
     def start(li, x):                                  # x: [1, P, F]
         """Issue layer ``li``'s exchange on this worker.  P2P tokens stop
         at the ``ppermute`` (packed hop rows, no unpack); all-gather
@@ -1346,53 +1356,62 @@ def _make_aggregate_shard(graph: dict, meta: DistMeta, policy: CommPolicy,
         f = x.shape[-1]
         xq = x[0]
         if not policy.communicates:
-            out = jnp.zeros((p_sz + 1, f), x.dtype)
-            out = out.at[graph["local_dst"][0]].add(
-                graph["local_w_iso"][0][:, None] * xq[graph["local_src"][0]])
-            return out[:p_sz][None]
+            with jax.named_scope("local_agg"):
+                out = jnp.zeros((p_sz + 1, f), x.dtype)
+                out = out.at[graph["local_dst"][0]].add(
+                    graph["local_w_iso"][0][:, None] *
+                    xq[graph["local_src"][0]])
+                return out[:p_sz][None]
 
         if p2p_wire:
             hops, k_call, n_keep, call = token
-            ell_w = _ell_w_for(graph, policy, rate)[0]
-            if dead is not None:
-                me = lax.axis_index(axis)
-                mix = _dead_mix(meta, dead)[me]
-                ell_w = ell_w + mix * (graph["ell_w_iso"][0] - ell_w)
-            loc = ell_aggregate(xq, graph["ell_nbr"][0], ell_w,
-                                graph["ell_rnbr"][0], graph["ell_rslot"][0])
-            halo = neighbor_exchange_finish(hops, axis, key=k_call,
-                                            n_keep=n_keep, f=f)
-            if q > 1 and (fcache is not None or dead is not None):
-                # receiver-side fault service: hop d's rows came from
-                # sender (me - d) mod q; substitute the cached block for
-                # CACHED pairs, zeros for DEAD ones (emulated-identical)
-                me = lax.axis_index(axis)
-                src = (me - jnp.arange(1, q)) % q              # [D]
-                hal3 = halo.reshape(q - 1, -1, f)
-                if fcache is not None:
-                    fsk = fskip[me, src]
-                    hal3 = jnp.where(fsk[:, None, None] > 0.0,
-                                     fcache[call][0], hal3)
-                if fcache_out is not None:
-                    fcache_out.append(hal3[None])
+            with jax.named_scope("local_agg"):
+                ell_w = _ell_w_for(graph, policy, rate)[0]
                 if dead is not None:
-                    dd = dead[me, src]
-                    hal3 = jnp.where(dd[:, None, None] > 0.0,
-                                     jnp.zeros_like(hal3), hal3)
-                halo = hal3.reshape(-1, f)
-            rem = jnp.zeros((p_sz + 1, f), x.dtype)
-            rem = rem.at[graph["remote_dst"][0]].add(
-                graph["remote_w"][0][:, None] *
-                halo[graph["remote_src_p2p"][0]])
+                    me = lax.axis_index(axis)
+                    mix = _dead_mix(meta, dead)[me]
+                    ell_w = ell_w + mix * (graph["ell_w_iso"][0] - ell_w)
+                loc = ell_aggregate(xq, graph["ell_nbr"][0], ell_w,
+                                    graph["ell_rnbr"][0],
+                                    graph["ell_rslot"][0])
+            with jax.named_scope("halo"):
+                halo = neighbor_exchange_finish(hops, axis, key=k_call,
+                                                n_keep=n_keep, f=f)
+                if q > 1 and (fcache is not None or dead is not None):
+                    # receiver-side fault service: hop d's rows came from
+                    # sender (me - d) mod q; substitute the cached block
+                    # for CACHED pairs, zeros for DEAD ones
+                    # (emulated-identical)
+                    me = lax.axis_index(axis)
+                    src = (me - jnp.arange(1, q)) % q              # [D]
+                    hal3 = halo.reshape(q - 1, -1, f)
+                    if fcache is not None:
+                        fsk = fskip[me, src]
+                        hal3 = jnp.where(fsk[:, None, None] > 0.0,
+                                         fcache[call][0], hal3)
+                    if fcache_out is not None:
+                        fcache_out.append(hal3[None])
+                    if dead is not None:
+                        dd = dead[me, src]
+                        hal3 = jnp.where(dd[:, None, None] > 0.0,
+                                         jnp.zeros_like(hal3), hal3)
+                    halo = hal3.reshape(-1, f)
+            with jax.named_scope("remote_agg"):
+                rem = jnp.zeros((p_sz + 1, f), x.dtype)
+                rem = rem.at[graph["remote_dst"][0]].add(
+                    graph["remote_w"][0][:, None] *
+                    halo[graph["remote_src_p2p"][0]])
             return (loc + rem[:p_sz])[None]
 
         halo = token
-        out = jnp.zeros((p_sz + 1, f), x.dtype)
-        out = out.at[graph["local_dst"][0]].add(
-            _local_w_for(graph, policy, rate)[0][:, None] *
-            xq[graph["local_src"][0]])
-        out = out.at[graph["remote_dst"][0]].add(
-            graph["remote_w"][0][:, None] * halo[graph["remote_src"][0]])
+        with jax.named_scope("local_agg"):
+            out = jnp.zeros((p_sz + 1, f), x.dtype)
+            out = out.at[graph["local_dst"][0]].add(
+                _local_w_for(graph, policy, rate)[0][:, None] *
+                xq[graph["local_src"][0]])
+        with jax.named_scope("remote_agg"):
+            out = out.at[graph["remote_dst"][0]].add(
+                graph["remote_w"][0][:, None] * halo[graph["remote_src"][0]])
         return out[:p_sz][None]
 
     def aggregate(li, x):
@@ -1425,11 +1444,18 @@ def _local_loss_fn(params, cfg: GNNConfig, graph: dict, aggregate,
     return loss_sum / max(meta.n_train, 1), bits
 
 
+@jax.named_scope("grad_sync")
 def _pmean_inexact(tree, axis: str):
     """FedAvg server step: average float state, keep integer state local."""
     return jax.tree_util.tree_map(
         lambda t: lax.pmean(t, axis)
         if jnp.issubdtype(t.dtype, jnp.inexact) else t, tree)
+
+
+@jax.named_scope("grad_sync")
+def _psum_tree(tree, axis: str):
+    """Gradient sync: sum every leaf over the workers."""
+    return jax.tree_util.tree_map(lambda t: lax.psum(t, axis), tree)
 
 
 def _step_metrics(loss, rate, bits) -> dict:
@@ -1542,10 +1568,9 @@ def make_train_step(cfg: GNNConfig, policy: CommPolicy, opt: Optimizer,
 
             (loss, bits), grads = jax.value_and_grad(loss_fn,
                                                      has_aux=True)(params)
-            loss = lax.psum(loss, AXIS)
+            loss = _psum_tree(loss, AXIS)
             if sync == "grad":
-                grads = jax.tree_util.tree_map(lambda g: lax.psum(g, AXIS),
-                                               grads)
+                grads = _psum_tree(grads, AXIS)
                 updates, new_state = opt.update(grads, opt_state, params)
                 params = apply_updates(params, updates)
             else:  # fedavg: local step, then parameter averaging
@@ -1619,6 +1644,7 @@ def make_eval_step(cfg: GNNConfig, meta: DistMeta, mesh: Mesh | None = None):
 
     if mesh is None:
         @jax.jit
+        @jax.named_scope("eval")
         def evaluate(params, graph):
             agg = _make_aggregate_emulated(graph, meta, FULL_COMM, None,
                                            jnp.ones((), jnp.float32),
@@ -1637,7 +1663,8 @@ def make_eval_step(cfg: GNNConfig, meta: DistMeta, mesh: Mesh | None = None):
 
     sm = shard_map(worker, mesh=mesh, in_specs=(P(), P(AXIS)),
                    out_specs=P(), check_vma=False)
-    return jax.jit(sm)
+    # the scope wraps shard_map, so the constants it hoists are named too
+    return jax.jit(jax.named_scope("eval")(sm))
 
 
 def make_infer_step(cfg: GNNConfig, policy: CommPolicy, meta: DistMeta,

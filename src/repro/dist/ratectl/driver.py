@@ -38,7 +38,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -46,7 +45,7 @@ from repro.core.varco import CommPolicy
 from repro.dist.gnn_parallel import (AXIS, COMPILED_CACHE_SIZE, DistMeta,
                                      _local_loss_fn, _make_aggregate_emulated,
                                      _make_aggregate_shard, _pmean_inexact,
-                                     plan_statics)
+                                     _psum_tree, plan_statics)
 from repro.dist.ratectl.base import RateController, RatePlan, make_pacing
 from repro.dist.ratectl.budget import budget_controller
 from repro.dist.ratectl.error import error_controller
@@ -352,10 +351,9 @@ def make_auto_train_step(cfg: GNNConfig, policy: CommPolicy, opt: Optimizer,
 
             (loss, (bits, cache_new)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params)
-            loss = lax.psum(loss, AXIS)
+            loss = _psum_tree(loss, AXIS)
             if sync == "grad":
-                grads = jax.tree_util.tree_map(lambda g: lax.psum(g, AXIS),
-                                               grads)
+                grads = _psum_tree(grads, AXIS)
                 updates, new_state = opt.update(grads, opt_state, params)
                 params = apply_updates(params, updates)
             else:  # fedavg

@@ -47,6 +47,10 @@ class GNNConfig:
         return list(zip(ds[:-1], ds[1:]))
 
 
+#: the layers' dense products, named as such in the compiled program
+_dense = jax.named_scope("dense")(dense)
+
+
 def init_gnn(key: Array, cfg: GNNConfig) -> dict:
     params: dict = {"layers": []}
     for li, (d_in, d_out) in enumerate(cfg.dims()):
@@ -102,34 +106,37 @@ def gnn_forward(params: dict, cfg: GNNConfig, x: Array,
     pipelined = start is not None and complete is not None
 
     for li, layer in enumerate(params["layers"]):
-        if cfg.conv == "sage":
-            if pipelined:
-                token, b = start(li, h)                # issue the exchange
-                self_term = dense(layer["self"], h)    # overlaps the wire
-                agg = complete(li, h, token)           # unpack + aggregate
-                bits = bits + b
-                h_new = self_term + dense(layer["neigh"], agg)
-            else:
-                agg, b = aggregate(li, h)
-                bits = bits + b
-                h_new = dense(layer["self"], h) + dense(layer["neigh"], agg)
-        else:  # poly, eq. (2) — taps chain (tap t+1 consumes tap t), so
-            # there is no exchange-independent work to interleave and the
-            # fused call is the pipelined schedule already
-            sk = h
-            h_new = dense(layer["taps"][0], h)
-            for t in range(1, cfg.k_taps):
-                sk, b = aggregate(li, sk)
-                bits = bits + b
-                h_new = h_new + dense(layer["taps"][t], sk)
-        if cfg.residual and h_new.shape == h.shape:
-            h_new = h_new + h
-        h = jax.nn.relu(h_new) if li < n_layers - 1 else h_new
+        with jax.named_scope(f"layer{li}"):
+            if cfg.conv == "sage":
+                if pipelined:
+                    token, b = start(li, h)                # issue the exchange
+                    self_term = _dense(layer["self"], h)   # overlaps the wire
+                    agg = complete(li, h, token)           # unpack + aggregate
+                    bits = bits + b
+                    h_new = self_term + _dense(layer["neigh"], agg)
+                else:
+                    agg, b = aggregate(li, h)
+                    bits = bits + b
+                    h_new = _dense(layer["self"], h) + \
+                        _dense(layer["neigh"], agg)
+            else:  # poly, eq. (2) — taps chain (tap t+1 consumes tap t), so
+                # there is no exchange-independent work to interleave and
+                # the fused call is the pipelined schedule already
+                sk = h
+                h_new = _dense(layer["taps"][0], h)
+                for t in range(1, cfg.k_taps):
+                    sk, b = aggregate(li, sk)
+                    bits = bits + b
+                    h_new = h_new + _dense(layer["taps"][t], sk)
+            if cfg.residual and h_new.shape == h.shape:
+                h_new = h_new + h
+            h = jax.nn.relu(h_new) if li < n_layers - 1 else h_new
         if hidden_out is not None:
             hidden_out.append(h)
     return h, bits
 
 
+@jax.named_scope("loss")
 def masked_loss_and_correct(logits: Array, labels: Array, mask: Array
                             ) -> tuple[Array, Array]:
     """Sum of CE over masked nodes + count of correct predictions."""

@@ -18,6 +18,8 @@ Array = jax.Array
 
 
 class Optimizer(NamedTuple):
+    """The optimizers built here, and :func:`apply_updates`, trace under
+    the ``optimizer`` scope (``repro.tracing``)."""
     init: Callable
     update: Callable  # (grads, state, params) -> (updates, state)
 
@@ -51,6 +53,7 @@ def sgd(lr: float | Callable, momentum: float = 0.0,
         mom = _tree_map(jnp.zeros_like, params) if momentum else None
         return {"step": jnp.zeros((), jnp.int32), "mom": mom}
 
+    @jax.named_scope("optimizer")
     def update(grads, state, params):
         step = state["step"] + 1
         eta = lr_fn(step)
@@ -86,6 +89,7 @@ def adamw(lr: float | Callable, b1: float = 0.9, b2: float = 0.999,
                 "mu": _tree_map(zeros, params),
                 "nu": _tree_map(zeros, params)}
 
+    @jax.named_scope("optimizer")
     def update(grads, state, params):
         step = state["step"] + 1
         t = step.astype(jnp.float32)
@@ -121,6 +125,7 @@ def adamw(lr: float | Callable, b1: float = 0.9, b2: float = 0.999,
     return Optimizer(init, update)
 
 
+@jax.named_scope("optimizer")
 def apply_updates(params, updates):
     return _tree_map(lambda p, u: p + u.astype(p.dtype), params, updates)
 

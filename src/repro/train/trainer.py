@@ -8,13 +8,13 @@ can be plotted against epochs (Fig. 3) or communicated floats (Fig. 5).
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core.varco import CommPolicy
 from repro.dist import faults as faultlib
 from repro.dist.gnn_parallel import (DistMeta, make_eval_step,
@@ -106,10 +106,13 @@ class History:
 
 @dataclasses.dataclass
 class TrainResult:
+    """``spans`` is the run's host span table (``repro.tracing.table``):
+    ``{name: {count, total_s, first_s}}``."""
     history: History
     params: Any
     meta: DistMeta
     policy_desc: str
+    spans: dict = dataclasses.field(default_factory=dict)
 
 
 def train_gnn(g: "GraphData | ShardSet | str", *, q: int = 8,
@@ -171,26 +174,38 @@ def train_gnn(g: "GraphData | ShardSet | str", *, q: int = 8,
     continues at the saved epoch, bitwise-equal to the uninterrupted
     run.  Resume replays any recorded worker shrink but refuses a
     checkpoint whose world size cannot be reached from ``g``.
+
+    The run's host work is timed in ``repro.tracing`` spans (set-up
+    phases ``setup/*``: partition, params, device_arrays, p2p, meta,
+    build; per epoch ``epoch`` around ``step``, ``sync``,
+    ``eval``, ``plan``/``observe``, ``log``, ``checkpoint``), returned as
+    ``TrainResult.spans`` and visible in a profiler trace of the call.
     """
+    tracing.reset()
     auto = policy.mode == "auto"
     fault = faults is not None
     if (auto or fault) and wire == "dense":
         wire = "p2p"                   # per-pair rates need a per-pair wire
     sched = faults
-    if is_shard_dir(g):
-        g = load_shards(g)
+    with tracing.span("setup/partition"):
+        if is_shard_dir(g):
+            g = load_shards(g)
+        if isinstance(g, ShardSet):
+            pg = g                     # partitioned offline; q comes with it
+            q = pg.q
+        else:
+            pg = partition_graph(g, q, scheme=scheme, seed=seed)
     cfg = GNNConfig(conv=conv, in_dim=g.feat_dim, hidden=hidden,
                     out_dim=g.num_classes, layers=layers)
-    params = init_gnn(jax.random.key(seed), cfg)
-    if isinstance(g, ShardSet):
-        pg = g                         # partitioned offline; q comes with it
-        q = pg.q
-        graph = pg.device_arrays()     # halo/ELL arrays ship in the shards
-    else:
-        pg = partition_graph(g, q, scheme=scheme, seed=seed)
-        graph = pg.device_arrays()
-        if wire == "p2p" or auto:
-            from repro.dist.halo import attach_p2p
+    # device allocations in this order: parameters, graph, optimizer
+    # state (the device memory layout follows it)
+    with tracing.span("setup/params"):
+        params = init_gnn(jax.random.key(seed), cfg)
+    with tracing.span("setup/device_arrays"):
+        graph = pg.device_arrays()     # shards carry their halo/ELL arrays
+    if not isinstance(pg, ShardSet) and (wire == "p2p" or auto):
+        from repro.dist.halo import attach_p2p
+        with tracing.span("setup/p2p"):
             graph = attach_p2p(graph, pg)  # auto's per-pair stats need them
     if resume:
         if not checkpoint_dir:
@@ -212,20 +227,19 @@ def train_gnn(g: "GraphData | ShardSet | str", *, q: int = 8,
                 pg = faultlib.shrink_shards(pg, cur.index(w))
                 cur.remove(w)
             q = pg.q
-            graph = pg.device_arrays()
+            with tracing.span("setup/device_arrays"):
+                graph = pg.device_arrays()
             if sched is not None:
                 sched = dataclasses.replace(
                     sched, alive=tuple(int(a) for a in alive))
         if int(peeked.get("q", q)) != q:
             raise ValueError(f"checkpoint world size {peeked['q']} does "
                              f"not match this run's q={q}")
-    meta = DistMeta.build(pg, params, wire=wire)
-    opt = optimizer or adamw(lr, weight_decay=weight_decay)
-    opt_state = opt.init(params)
+    with tracing.span("setup/meta"):
+        meta = DistMeta.build(pg, params, wire=wire)
+        opt = optimizer or adamw(lr, weight_decay=weight_decay)
+        opt_state = opt.init(params)
 
-    mesh = make_worker_mesh(q) if use_shard_map else None
-    if mesh is not None:
-        graph = shard_graph(graph, mesh)
     if auto or fault:
         from repro.dist.ratectl import (init_halo_cache, init_wire_residuals,
                                         make_auto_train_step, make_controller,
@@ -251,15 +265,19 @@ def train_gnn(g: "GraphData | ShardSet | str", *, q: int = 8,
                                         sync=sync)
         return make_train_step(cfg, policy, opt, meta_, mesh=mesh, sync=sync)
 
-    ctl = ctl_state = None
-    if auto:
-        ctl = make_controller(policy, meta, cfg, total_steps=epochs)
-        ctl_state = ctl.init()
-    cache = _init_cache(meta)
-    fcache = init_halo_cache(meta, cfg) if fault else ()
-    dstate = faultlib.init_degrade(q) if fault else None
-    step = _make_step(meta)
-    evaluate = make_eval_step(cfg, meta, mesh=mesh)
+    with tracing.span("setup/build"):
+        mesh = make_worker_mesh(q) if use_shard_map else None
+        if mesh is not None:
+            graph = shard_graph(graph, mesh)
+        ctl = ctl_state = None
+        if auto:
+            ctl = make_controller(policy, meta, cfg, total_steps=epochs)
+            ctl_state = ctl.init()
+        cache = _init_cache(meta)
+        fcache = init_halo_cache(meta, cfg) if fault else ()
+        dstate = faultlib.init_degrade(q) if fault else None
+        step = _make_step(meta)
+        evaluate = make_eval_step(cfg, meta, mesh=mesh)
 
     hist = History()
     halo_bits_cum = 0.0
@@ -321,108 +339,122 @@ def train_gnn(g: "GraphData | ShardSet | str", *, q: int = 8,
         if ext.get("layer") is not None:
             layer_bits_cum = np.asarray(ext["layer"], np.float64)
 
-    t0 = time.time()
+    t0 = tracing.clock()
     for epoch in range(start_epoch, epochs):
-        if fault:
-            crash = sched.crash_at_step(epoch)
-            if crash is not None:
-                if not isinstance(pg, ShardSet):
-                    raise ValueError(
-                        "elastic worker-crash recovery needs shard-backed "
-                        "input (a ShardSet / shard dir) — in-memory "
-                        "partitions cannot be renumbered at Q - 1")
-                if q <= 2:
-                    raise ValueError("cannot shrink below Q = 2 — the "
-                                     "fault plane needs at least one link")
-                q_old = q
-                pg = faultlib.shrink_shards(pg, crash)
-                q = pg.q
-                graph = pg.device_arrays()
-                meta = DistMeta.build(pg, params, wire=wire)
-                mesh = make_worker_mesh(q) if use_shard_map else None
-                if mesh is not None:
-                    graph = shard_graph(graph, mesh)
-                sched = sched.shrink(crash)
-                dstate = faultlib.migrate_degrade_state(dstate, crash)
+        with tracing.span("epoch", step_num=epoch):
+            if fault:
+                crash = sched.crash_at_step(epoch)
+                if crash is not None:
+                    if not isinstance(pg, ShardSet):
+                        raise ValueError(
+                            "elastic worker-crash recovery needs shard-backed "
+                            "input (a ShardSet / shard dir) — in-memory "
+                            "partitions cannot be renumbered at Q - 1")
+                    if q <= 2:
+                        raise ValueError("cannot shrink below Q = 2 — the "
+                                         "fault plane needs at least one link")
+                    q_old = q
+                    pg = faultlib.shrink_shards(pg, crash)
+                    q = pg.q
+                    graph = pg.device_arrays()
+                    meta = DistMeta.build(pg, params, wire=wire)
+                    mesh = make_worker_mesh(q) if use_shard_map else None
+                    if mesh is not None:
+                        graph = shard_graph(graph, mesh)
+                    sched = sched.shrink(crash)
+                    dstate = faultlib.migrate_degrade_state(dstate, crash)
+                    if auto:
+                        ctl = make_controller(policy, meta, cfg,
+                                              total_steps=epochs)
+                        ctl_state = faultlib.migrate_controller_state(
+                            ctl_state, crash, q_old)
+                    cache = _init_cache(meta)   # stale/EF buffers restart cold
+                    fcache = init_halo_cache(meta, cfg)
+                    step = _make_step(meta)
+                    evaluate = make_eval_step(cfg, meta, mesh=mesh)
+                    # keep cumulative pair splits shaped [..., Q, Q]: the dead
+                    # worker's history leaves the ledger with it
+                    if pair_bits_cum is not None:
+                        pair_bits_cum = np.delete(
+                            np.delete(pair_bits_cum, crash, 0), crash, 1)
+                    if layer_bits_cum is not None:
+                        layer_bits_cum = np.delete(
+                            np.delete(layer_bits_cum, crash, 1), crash, 2)
+                serve, dstate = faultlib.degrade_plan(
+                    dstate, sched.effective_drops(epoch), epoch,
+                    max_stale=fault_max_stale, backoff_cap=fault_backoff_cap)
+                fskip, dead = faultlib.serve_masks(serve)
+            if fault:
+                with tracing.span("plan"):
+                    if auto:
+                        plan, ctl_state = ctl.plan(ctl_state, epoch)
+                    else:
+                        r = float(policy.rate(epoch)) \
+                            if policy.compresses else 1.0
+                        plan = uniform_plan(q, r)
+                with tracing.span("step"):
+                    params, opt_state, m, cache, fcache = step(
+                        params, opt_state, graph, jax.random.key(epoch),
+                        plan, fskip, dead, cache, fcache)
                 if auto:
-                    ctl = make_controller(policy, meta, cfg,
-                                          total_steps=epochs)
-                    ctl_state = faultlib.migrate_controller_state(
-                        ctl_state, crash, q_old)
-                cache = _init_cache(meta)   # stale/EF buffers restart cold
-                fcache = init_halo_cache(meta, cfg)
-                step = _make_step(meta)
-                evaluate = make_eval_step(cfg, meta, mesh=mesh)
-                # keep cumulative pair splits shaped [..., Q, Q]: the dead
-                # worker's history leaves the ledger with it
-                if pair_bits_cum is not None:
-                    pair_bits_cum = np.delete(
-                        np.delete(pair_bits_cum, crash, 0), crash, 1)
-                if layer_bits_cum is not None:
-                    layer_bits_cum = np.delete(
-                        np.delete(layer_bits_cum, crash, 1), crash, 2)
-            serve, dstate = faultlib.degrade_plan(
-                dstate, sched.effective_drops(epoch), epoch,
-                max_stale=fault_max_stale, backoff_cap=fault_backoff_cap)
-            fskip, dead = faultlib.serve_masks(serve)
-        if fault:
-            if auto:
-                plan, ctl_state = ctl.plan(ctl_state, epoch)
+                    with tracing.span("observe"):
+                        ctl_state = ctl.observe(ctl_state, m)
+            elif auto:
+                with tracing.span("plan"):
+                    plan, ctl_state = ctl.plan(ctl_state, epoch)
+                with tracing.span("step"):
+                    params, opt_state, m, cache = step(
+                        params, opt_state, graph, jax.random.key(epoch),
+                        plan, cache)
+                with tracing.span("observe"):
+                    ctl_state = ctl.observe(ctl_state, m)
             else:
-                r = float(policy.rate(epoch)) if policy.compresses else 1.0
-                plan = uniform_plan(q, r)
-            params, opt_state, m, cache, fcache = step(
-                params, opt_state, graph, jax.random.key(epoch), plan,
-                fskip, dead, cache, fcache)
-            if auto:
-                ctl_state = ctl.observe(ctl_state, m)
-        elif auto:
-            plan, ctl_state = ctl.plan(ctl_state, epoch)
-            params, opt_state, m, cache = step(params, opt_state, graph,
-                                               jax.random.key(epoch), plan,
-                                               cache)
-            ctl_state = ctl.observe(ctl_state, m)
-        else:
-            params, opt_state, m = step(params, opt_state, graph,
-                                        jnp.asarray(epoch),
-                                        jax.random.key(epoch))
-        if auto or fault:
-            pair_t = np.asarray(m["pair_transport"], np.float64)
-            pair_bits_cum = pair_t if pair_bits_cum is None \
-                else pair_bits_cum + pair_t
-            err_cum += float(np.asarray(m["pair_err"], np.float64).sum())
-            if "layer_transport" in m:
-                layer_t = np.asarray(m["layer_transport"], np.float64)
-                layer_bits_cum = layer_t if layer_bits_cum is None \
-                    else layer_bits_cum + layer_t
-        halo_bits_cum += float(m["halo_bits"])
-        transport_bits_cum += float(m["transport_bits"])
-        if epoch % eval_every == 0 or epoch == epochs - 1:
-            accs = evaluate(params, graph)
-            hist.epoch.append(epoch)
-            hist.loss.append(float(m["loss"]))
-            hist.rate.append(float(m["rate"]))
-            hist.train_acc.append(float(accs["train"]))
-            hist.val_acc.append(float(accs["val"]))
-            hist.test_acc.append(float(accs["test"]))
-            hist.halo_gfloats.append(halo_bits_cum / 32.0 / 1e9)
-            hist.transport_gfloats.append(transport_bits_cum / 32.0 / 1e9)
-            hist.wall_s.append(time.time() - t0)
-            if pair_bits_cum is not None:
-                hist.pair_transport_gf.append(tuple(
-                    pair_bits_cum.ravel() / 32.0 / 1e9))
-                hist.comp_err.append(err_cum)
-            if layer_bits_cum is not None:
-                hist.layer_transport_gf.append(tuple(
-                    layer_bits_cum.ravel() / 32.0 / 1e9))
-            if log_fn:
-                log_fn(hist.row(len(hist.epoch) - 1))
-        done = epoch + 1
-        if checkpoint_dir and (
-                (checkpoint_every and done % checkpoint_every == 0)
-                or done == stop_after):
-            ckpt.save_train_state(checkpoint_dir, _state_tree(), done,
-                                  extra=_ck_extra())
-        if stop_after is not None and done >= stop_after:
-            break
-    return TrainResult(hist, params, meta, policy.describe())
+                with tracing.span("step"):
+                    params, opt_state, m = step(params, opt_state, graph,
+                                                jnp.asarray(epoch),
+                                                jax.random.key(epoch))
+            if auto or fault:
+                pair_t = tracing.pull(m["pair_transport"])
+                pair_bits_cum = pair_t if pair_bits_cum is None \
+                    else pair_bits_cum + pair_t
+                err_cum += float(tracing.pull(m["pair_err"]).sum())
+                if "layer_transport" in m:
+                    layer_t = tracing.pull(m["layer_transport"])
+                    layer_bits_cum = layer_t if layer_bits_cum is None \
+                        else layer_bits_cum + layer_t
+            halo_bits_cum += tracing.pull(m["halo_bits"])
+            transport_bits_cum += tracing.pull(m["transport_bits"])
+            if epoch % eval_every == 0 or epoch == epochs - 1:
+                with tracing.span("eval"):
+                    accs = evaluate(params, graph)
+                hist.epoch.append(epoch)
+                hist.loss.append(tracing.pull(m["loss"]))
+                hist.rate.append(tracing.pull(m["rate"]))
+                hist.train_acc.append(tracing.pull(accs["train"]))
+                hist.val_acc.append(tracing.pull(accs["val"]))
+                hist.test_acc.append(tracing.pull(accs["test"]))
+                hist.halo_gfloats.append(halo_bits_cum / 32.0 / 1e9)
+                hist.transport_gfloats.append(
+                    transport_bits_cum / 32.0 / 1e9)
+                hist.wall_s.append(tracing.clock() - t0)
+                if pair_bits_cum is not None:
+                    hist.pair_transport_gf.append(tuple(
+                        pair_bits_cum.ravel() / 32.0 / 1e9))
+                    hist.comp_err.append(err_cum)
+                if layer_bits_cum is not None:
+                    hist.layer_transport_gf.append(tuple(
+                        layer_bits_cum.ravel() / 32.0 / 1e9))
+                if log_fn:
+                    with tracing.span("log"):
+                        log_fn(hist.row(len(hist.epoch) - 1))
+            done = epoch + 1
+            if checkpoint_dir and (
+                    (checkpoint_every and done % checkpoint_every == 0)
+                    or done == stop_after):
+                with tracing.span("checkpoint"):
+                    ckpt.save_train_state(checkpoint_dir, _state_tree(),
+                                          done, extra=_ck_extra())
+            if stop_after is not None and done >= stop_after:
+                break
+    return TrainResult(hist, params, meta, policy.describe(),
+                       tracing.table())
