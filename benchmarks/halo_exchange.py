@@ -3,7 +3,7 @@
 The packed wire (DESIGN.md §3.3) shrinks the all-gather payload from
 ``[B, F]`` to ``[B, K·128]``; the p2p wire (§3.5) replaces the all-gather
 itself with a ``ppermute`` ring that ships each peer only its per-pair
-halo rows, with the local edges on the ``ell_spmm`` kernel path.  This
+halo rows, with the local edges on the ELL row gathers.  This
 sweep *measures* both reductions instead of asserting them — per
 (Q, F, rate) on a METIS-like-partitioned synthetic citation graph it
 records the analytic point-to-point charge, each wire's transport charge,

@@ -31,9 +31,9 @@ varco_pack kernels), so the wire volume itself drops with the rate;
 ``"p2p"`` replaces the all-gather entirely with a neighbor-only
 ``ppermute`` ring (:func:`repro.core.collectives.neighbor_exchange`) that
 ships each peer only the per-pair halo rows it references
-(``repro.dist.halo``), and runs the local-edge aggregation through the
-``ell_spmm`` kernel path (:func:`repro.kernels.ops.ell_aggregate`) while
-the hops are in flight — transport equals the analytic point-to-point
+(``repro.dist.halo``), and runs the local-edge aggregation as ELL row
+gathers (:func:`repro.kernels.ops.ell_aggregate`) while the hops are in
+flight — transport equals the analytic point-to-point
 charge at every rate.  All formats draw the same per-worker masks, so
 packed / p2p and dense-``blockmask`` runs deliver identical remote values;
 wire buffer shapes are set by the static kept-block counts, which each
@@ -1133,7 +1133,7 @@ def _make_aggregate_shard(graph: dict, meta: DistMeta, policy: CommPolicy,
     ``[B, K·128]`` lane-block payload.  P2P wire:
     :func:`repro.core.collectives.neighbor_exchange` — ``Q - 1`` ppermute
     hops carrying only the per-pair halo rows, with the local edges on the
-    :func:`repro.kernels.ops.ell_aggregate` kernel path so XLA can overlap
+    :func:`repro.kernels.ops.ell_aggregate` row gathers so XLA can overlap
     the hops with the local compute.  The per-worker masks derive from the
     same ``fold_in`` streams as the emulated path, so both backends agree
     bitwise.

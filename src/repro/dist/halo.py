@@ -16,9 +16,10 @@ exactly that:
   (hop ``d`` occupies rows ``[(d-1)·H, d·H)``), replacing the flattened
   ``[Q·B]`` all-gather buffer;
 * **degree-padded ELL neighbour lists** for the local edges — forward
-  lists ``(nbr, w, w_iso)`` for the ``ell_spmm`` kernel plus the
-  *reversed* lists ``(rnbr, rslot)`` whose ELL SpMM is the forward's
-  transpose (the custom VJP of :func:`repro.kernels.ops.ell_aggregate`).
+  lists ``(nbr, w, w_iso)`` for the row gathers of
+  :func:`repro.kernels.ops.ell_aggregate` plus the *reversed* lists
+  ``(rnbr, rslot)`` whose ELL SpMM is the forward's transpose (its custom
+  VJP).
 
 Everything here is plain numpy; :func:`attach_p2p` merges the device
 arrays into the graph pytree consumed by ``repro.dist.gnn_parallel`` (all
@@ -221,8 +222,8 @@ def build_reverse_ell(nbr: np.ndarray, valid: np.ndarray, n_src: int,
     marks genuine entries.  Returns ``(rnbr [n_src, RK], rslot [n_src,
     RK])``: ``rnbr[s]`` lists the destination rows fed by source ``s`` and
     ``rslot[s]`` the flat ``i·K + k`` position of the matching forward
-    weight (``-1`` pad) — so ``ell_spmm(g, rnbr, w.ravel()[rslot])`` is the
-    exact transpose of ``ell_spmm(x, nbr, w)``.
+    weight (``-1`` pad) — so the ELL SpMM ``(g, rnbr, w.ravel()[rslot])``
+    is the exact transpose of the ELL SpMM ``(x, nbr, w)``.
     """
     n, k = nbr.shape
     d_idx, k_idx = np.nonzero(valid)
@@ -244,7 +245,7 @@ def build_reverse_ell(nbr: np.ndarray, valid: np.ndarray, n_src: int,
 def ell_arrays(pg, spec: HaloSpec | None = None) -> dict[str, np.ndarray]:
     """Degree-padded ELL lists of every partition's local edges.
 
-    ``ell_nbr/ell_w/ell_w_iso [Q, P, K]`` feed the forward ``ell_spmm``
+    ``ell_nbr/ell_w/ell_w_iso [Q, P, K]`` feed the forward ELL SpMM
     (pad entries carry weight 0); ``ell_rnbr/ell_rslot [Q, P, RK]`` are the
     reversed lists for the VJP transpose (:func:`build_reverse_ell`).
     """

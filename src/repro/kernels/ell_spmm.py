@@ -19,12 +19,13 @@ HBM traffic is one pass over ``x`` per node tile.  Validated against
 ``ref.ell_spmm_reference`` in interpret mode over shape sweeps incl.
 ragged/padded degrees.
 
-The kernel itself is forward-only; the runtime's differentiable entry
-point is :func:`repro.kernels.ops.ell_aggregate`, whose custom VJP runs
-the *transpose* — the same SpMM over the reversed neighbour lists
-(``repro.dist.halo.build_reverse_ell``) — so both directions of the p2p
-wire's local aggregation stay on this kernel on TPU and on the jnp oracle
-elsewhere.
+The kernel is forward-only, and is the :func:`repro.kernels.ops.aggregate`
+surface alone: no training path runs it.  The runtime's differentiable
+entry point, :func:`repro.kernels.ops.ell_aggregate`, runs the same SpMM
+on every backend as K row gathers of ``x`` with a per-slot weighted f32
+accumulate, work linear in ``N_dst·K·F``, and its custom VJP runs the
+transpose as the same gathers over the reversed neighbour lists
+(``repro.dist.halo.build_reverse_ell``).
 """
 
 from __future__ import annotations

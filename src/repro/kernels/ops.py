@@ -376,43 +376,29 @@ def aggregate(x, nbr, w, *, interpret: bool | None = None):
 # ---------------------------------------------------------------------------
 #
 # ``ell_aggregate`` is what ``repro.dist.gnn_parallel`` runs over each
-# partition's local edges on the p2p wire: the Pallas ``ell_spmm`` kernel on
-# TPU (rows padded to its grid), the ``ref.ell_spmm_reference`` jnp oracle
-# elsewhere (interpret-mode Pallas is far too slow for a train loop).  The
-# custom VJP keeps gradients on the same kernel path: the transpose of an
-# ELL SpMM is the ELL SpMM over the *reversed* neighbour lists
-# (``repro.dist.halo.build_reverse_ell``), whose weights are gathered from
-# the forward weights via the ``rslot`` flat map.
+# partition's local edges on the p2p wire, on every backend alike: K row
+# gathers of ``x``, one per ELL slot, each weighted and accumulated in f32
+# (``_ell_gather``).  Its work is linear in ``N_dst·K·F``; the Pallas
+# ``ell_spmm`` kernel sweeps every source chunk for every node tile, work
+# quadratic in the node count, and stays only as the forward-only
+# :func:`aggregate` surface.  The custom VJP keeps gradients on the same
+# gathers: the transpose of an ELL SpMM is the ELL SpMM over the *reversed*
+# neighbour lists (``repro.dist.halo.build_reverse_ell``), whose weights
+# are gathered from the forward weights via the ``rslot`` flat map.
 
 
-def _ell_cpu(x, nbr, w):
-    """Oracle-equivalent ELL SpMM for XLA:CPU/GPU: K-sliced fused
-    accumulation (k-ascending, like the kernel's einsum) instead of the
-    ``ref`` oracle's ``[N, K, F]`` gather materialisation, which dominates
-    the emulated train loop at realistic degrees."""
-    def body(k, acc):
+def _ell_gather(x, nbr, w):
+    """ELL SpMM as K row gathers with a per-slot weighted f32 accumulate,
+    slots summed in ascending ``k`` like ``ref.ell_spmm_reference``'s
+    einsum, without its ``[N, K, F]`` gather.  One loop iteration per
+    slot: unrolled slots keep their gathered rows live together, which
+    raises the step's peak device memory."""
+    def slot(k, acc):
         return acc + w[:, k].astype(jnp.float32)[:, None] * \
             x[nbr[:, k]].astype(jnp.float32)
 
     acc = jnp.zeros((nbr.shape[0], x.shape[1]), jnp.float32)
-    return jax.lax.fori_loop(0, nbr.shape[1], body, acc).astype(x.dtype)
-
-
-def _ell_impl(x, nbr, w):
-    if jax.default_backend() != "tpu":
-        return _ell_cpu(x, nbr, w)
-    n_dst, _ = nbr.shape
-    n_src, f = x.shape
-    tn = 128 if n_dst >= 128 else -(-n_dst // 8) * 8
-    sc = 1024 if n_src >= 1024 else -(-n_src // 8) * 8
-    bf = 128 if f % 128 == 0 else f
-    nd_p = -(-n_dst // tn) * tn
-    ns_p = -(-n_src // sc) * sc
-    xp = jnp.pad(x, ((0, ns_p - n_src), (0, 0))) if ns_p > n_src else x
-    nbr_p = jnp.pad(nbr, ((0, nd_p - n_dst), (0, 0))) if nd_p > n_dst else nbr
-    w_p = jnp.pad(w, ((0, nd_p - n_dst), (0, 0))) if nd_p > n_dst else w
-    out = ell_spmm(xp, nbr_p, w_p, tile_n=tn, block_f=bf, src_chunk=sc)
-    return out[:n_dst] if nd_p > n_dst else out
+    return jax.lax.fori_loop(0, nbr.shape[1], slot, acc).astype(x.dtype)
 
 
 @jax.custom_vjp
@@ -426,19 +412,19 @@ def ell_aggregate(x, nbr, w, rnbr, rslot):
     reversed-list ELL SpMM (the exact transpose of the forward).
     """
     del rnbr, rslot
-    return _ell_impl(x, nbr, w)
+    return _ell_gather(x, nbr, w)
 
 
 def _ell_aggregate_fwd(x, nbr, w, rnbr, rslot):
-    return _ell_impl(x, nbr, w), (x, nbr, w, rnbr, rslot)
+    return _ell_gather(x, nbr, w), (x, nbr, w, rnbr, rslot)
 
 
 def _ell_aggregate_bwd(res, g):
     x, nbr, w, rnbr, rslot = res
     rw = jnp.where(rslot >= 0, w.reshape(-1)[jnp.maximum(rslot, 0)], 0.0)
-    dx = _ell_impl(g, rnbr, rw).astype(x.dtype)
+    dx = _ell_gather(g, rnbr, rw).astype(x.dtype)
 
-    # dw[i, k] = <g[i], x[nbr[i, k]]> — K-sliced like _ell_cpu, never the
+    # dw[i, k] = <g[i], x[nbr[i, k]]> — K-sliced like _ell_gather, never the
     # [N, K, F] gather.  (In the train loop graph weights are not
     # differentiated, so XLA DCEs this branch entirely.)
     gf = g.astype(jnp.float32)

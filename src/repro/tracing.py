@@ -11,8 +11,8 @@ emulated and the ``shard_map`` backends:
 ``layer<i>``    everything GNN layer ``i`` does (``nn/gnn.py``)
 ``halo``        the halo exchange: send-buffer gather, pack/quantise,
                 exchange, unpack
-``local_agg``   aggregation over local edges (the ELL kernel on the p2p
-                wire, the local scatter on the dense/packed wires)
+``local_agg``   aggregation over local edges (the ELL row gathers on the
+                p2p wire, the local scatter on the dense/packed wires)
 ``remote_agg``  aggregation of the delivered halo rows
 ``dense``       the layers' dense products
 ``loss``        the masked cross-entropy

@@ -2,6 +2,7 @@
 sweeps (deliverable c)."""
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -186,9 +187,15 @@ def _random_ell(n_dst, n_src, k, seed, rev_k=None):
             jnp.asarray(rslot))
 
 
+#: shapes off the old Pallas grid (128-row node tiles, 1024-row source
+#: chunks) at the benchmark's ELL width and hidden width
+OFF_GRID = (300, 1100, 14, 256)
+
+
 @pytest.mark.parametrize("n_dst,n_src,k,f", [(64, 48, 6, 128),
                                              (128, 128, 3, 96),
-                                             (33, 17, 9, 64)])
+                                             (33, 17, 9, 64),
+                                             OFF_GRID])
 def test_ell_aggregate_forward_matches_reference(n_dst, n_src, k, f):
     nbr, w, rnbr, rslot = _random_ell(n_dst, n_src, k, seed=f)
     x = jnp.asarray(RNG.normal(0, 1, (n_src, f)), jnp.float32)
@@ -199,7 +206,8 @@ def test_ell_aggregate_forward_matches_reference(n_dst, n_src, k, f):
 
 
 @pytest.mark.parametrize("n_dst,n_src,k,f", [(64, 48, 6, 128),
-                                             (33, 17, 9, 64)])
+                                             (33, 17, 9, 64),
+                                             OFF_GRID])
 def test_ell_aggregate_gradient_matches_reference(n_dst, n_src, k, f):
     """Custom VJP (reversed-list transpose) vs autodiff of the jnp oracle —
     both d/dx and d/dw, under an arbitrary downstream cotangent."""
@@ -262,6 +270,57 @@ def test_ell_aggregate_transpose_is_exact():
             a[i, int(nbr[i, kk])] += float(w[i, kk])
     np.testing.assert_allclose(np.asarray(xt), a.T @ a @ np.asarray(x),
                                rtol=1e-4, atol=1e-4)
+
+
+def _jaxpr_vars(jaxpr):
+    """(primitive name, output avals) of every equation of ``jaxpr`` and of
+    the jaxprs nested in its equations' parameters."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name, [v.aval for v in eqn.outvars]
+        for p in eqn.params.values():
+            for sub in (p if isinstance(p, (tuple, list)) else (p,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if isinstance(sub, jax.extend.core.Jaxpr):
+                    yield from _jaxpr_vars(sub)
+
+
+def _largest_and_kernels(fn, *args):
+    found = list(_jaxpr_vars(jax.make_jaxpr(fn)(*args).jaxpr))
+    largest = max(int(np.prod(a.shape)) for _, avals in found for a in avals
+                  if hasattr(a, "shape"))
+    return largest, sum(name == "pallas_call" for name, _ in found)
+
+
+def test_ell_aggregate_gathers_rows_without_kernel_or_nkf(monkeypatch):
+    """The training path's local aggregation, forward and VJP, holds no
+    Pallas call and no intermediate as large as the ``[N_dst, K, F]``
+    gather: nothing larger than the largest ``[N, F]`` operand.  The
+    backend is steered to ``"tpu"`` so that a TPU-only branch would show."""
+    n_dst, n_src, k, f = OFF_GRID
+    nbr, w, rnbr, rslot = _random_ell(n_dst, n_src, k, seed=3)
+    x = jnp.asarray(RNG.normal(0, 1, (n_src, f)), jnp.float32)
+    g = jnp.asarray(RNG.normal(0, 1, (n_dst, f)), jnp.float32)
+    bound = max(n_dst, n_src) * f
+
+    # the checks can see what they guard against
+    largest, _ = _largest_and_kernels(ref.ell_spmm_reference, x, nbr, w)
+    assert largest >= n_dst * k * f
+    _, kernels = _largest_and_kernels(
+        lambda *a: ell_spmm(*a, interpret=False), x[:1024], nbr[:256], w[:256])
+    assert kernels == 1
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def fwd(x_, w_):
+        return ops.ell_aggregate(x_, nbr, w_, rnbr, rslot)
+
+    def bwd(x_, w_, g_):
+        return jax.vjp(fwd, x_, w_)[1](g_)
+
+    for fn, args in ((fwd, (x, w)), (bwd, (x, w, g))):
+        largest, kernels = _largest_and_kernels(fn, *args)
+        assert kernels == 0, fn.__name__
+        assert largest <= bound, (fn.__name__, largest, bound)
 
 
 # ---------------------------------------------------------------------------
