@@ -79,9 +79,11 @@ def main(argv=None) -> int:
         line = {"workload": args.workload, "seed": seed,
                 "program": kept["nums"], "correct": result["correct"]}
         arrays, model, ref = kept["arrays"], kept["model"], kept["ref"]
+        family = kept["family"]
         steps = harness.CHECKED_STEPS
         print(f"seed {seed}: control", file=sys.stderr, flush=True)
-        ctl = reference.train_readings(arrays, model, seed, steps, "high")
+        ctl = reference.train_readings(family, arrays, model, seed, steps,
+                                       "high")
         line["control"] = check.numbers(ctl, ref)
         if args.detail:
             line["detail"] = {"program": detail(kept["prog"], ref),
@@ -93,14 +95,15 @@ def main(argv=None) -> int:
             half["train_mask"] = arrays["train_mask"].copy()
             half["train_mask"][train[::2]] = False
             faults = {"half_batch": reference.train_readings(
-                half, model, seed, steps, model["matmul_precision"])}
+                family, half, model, seed, steps,
+                model["matmul_precision"])}
             owner = kept["owner"]
             n = len(arrays["indptr"]) - 1
             dst = np.repeat(np.arange(n), np.diff(arrays["indptr"]))
             local = owner[dst] == owner[arrays["indices"]]
             faults["no_exchange"] = reference.train_readings(
-                arrays, model, seed, steps, model["matmul_precision"],
-                edge_keep=local)
+                family, arrays, model, seed, steps,
+                model["matmul_precision"], edge_keep=local)
             line["faults"] = {k: check.numbers(v, ref)
                               for k, v in faults.items()}
         line["seconds"] = time.perf_counter() - t0

@@ -1,11 +1,13 @@
 """One run of one cell: set-up, the measured window, the check.
 
 A cell (``workloads/<name>.json``) names a configuration
-(``configs/<config>.json``: the model, the graph and its generator in
+(``configs/<config>.json``: the model, its family in
+``models/<family>.py``, the graph and its generator in
 ``graphs/<generator>.py``) and a traffic mix (``traffic/<traffic>.json``:
 partitions, scheme, policy, wire, evaluation period, job length, chips).
 The window drives the program's normal entry, ``repro.train.train_gnn``,
-for a job of ``horizon_epochs`` epochs:
+for a job of ``horizon_epochs`` epochs, with the model the family
+describes (``program_fields``):
 
 * set-up is everything from the start of the process to the end of
   epoch 0's evaluation: the graph, ``train_gnn``'s partition and halo
@@ -14,8 +16,9 @@ for a job of ``horizon_epochs`` epochs:
   ``--seconds`` or more after it started; ``epoch_s`` is its wall time
   over the epochs it completed, evaluations included;
 * ``correct`` compares the program's first steps, as the window's own
-  call produced them, with ``reference.py`` (see ``check.py``) once the
-  window has closed and the program's state is freed.
+  call produced them, with the family's reference (``reference.py``, see
+  ``check.py``) once the window has closed and the program's state is
+  freed.
 
 With ``trace`` the window runs under the profiler and the run reports
 the per-layer metrics of ``metrics/<name>.py`` instead of the end-to-end
@@ -25,6 +28,7 @@ ones.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import gc
 import importlib.util
 import json
@@ -39,6 +43,13 @@ import numpy as np
 BENCH = Path(__file__).resolve().parent
 CHECKOUT = BENCH.parent
 OUT = CHECKOUT / "bench_out"
+#: the directories searched, in order, for a cell's files by kind
+#: (``workloads``, ``configs``, ``traffic``, ``models``, ``graphs``,
+#: ``metrics``)
+ROOTS = [BENCH]
+#: the fields of the program's model that ``train_gnn`` takes by name;
+#: the others reach its ``GNNConfig`` bound to the class it builds
+TRAIN_GNN_FIELDS = ("conv", "hidden", "layers")
 
 #: the steps whose losses the check compares; their weights are read as
 #: the next step gets them
@@ -53,12 +64,20 @@ class WindowClosed(Exception):
     """Raised from ``train_gnn``'s log hook to end the job early."""
 
 
+def find(kind: str, file: str) -> Path:
+    for root in ROOTS:
+        if (root / kind / file).exists():
+            return root / kind / file
+    raise FileNotFoundError(f"no {kind}/{file} under "
+                            f"{[str(r) for r in ROOTS]}")
+
+
 def load(kind: str, name: str) -> dict:
-    return json.loads((BENCH / kind / f"{name}.json").read_text())
+    return json.loads(find(kind, f"{name}.json").read_text())
 
 
 def load_module(kind: str, name: str):
-    path = BENCH / kind / f"{name}.py"
+    path = find(kind, f"{name}.py")
     spec = importlib.util.spec_from_file_location(f"bench_{kind}_{name}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
@@ -157,6 +176,7 @@ class Run:
     traffic: dict
     chips: int
     device_kind: str
+    family: object                  # the models/<family>.py module
     peak: dict
     counts: dict
     in_dim: int
@@ -238,6 +258,10 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     from repro.graph.partition import PARTITIONERS
     from repro.train import trainer
 
+    family = load_module("models", model["family"])
+    fields = dict(family.program_fields(model))
+    named = {k: fields.pop(k) for k in TRAIN_GNN_FIELDS}
+
     clock = CompileClock()
     arrays = graph_arrays(cfg, seed)
     n = len(arrays["indptr"]) - 1
@@ -270,25 +294,28 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     window = Window(seconds, on_open=on_open,
                     on_close=jax.profiler.stop_trace if trace else None)
     recorder = StepRecorder(CHECKED_STEPS + 1)
-    patches = [(trainer, "make_train_step"),
-               (ratectl, "make_auto_train_step")]
-    saved = [getattr(mod, name) for mod, name in patches]
-    for mod, name in patches:
-        setattr(mod, name, recorder.wrap(getattr(mod, name)))
+    patches = [(trainer, "make_train_step",
+                recorder.wrap(trainer.make_train_step)),
+               (ratectl, "make_auto_train_step",
+                recorder.wrap(ratectl.make_auto_train_step)),
+               (trainer, "GNNConfig",
+                functools.partial(trainer.GNNConfig, **fields))]
+    saved = [getattr(mod, name) for mod, name, _ in patches]
+    for mod, name, fn in patches:
+        setattr(mod, name, fn)
     try:
         with jax.default_matmul_precision(model["matmul_precision"]):
             trainer.train_gnn(
                 g, q=q, scheme=traffic["scheme"], policy=policy,
                 epochs=traffic["horizon_epochs"], lr=model["optimizer"]["lr"],
-                hidden=model["hidden"], layers=model["num_layers"],
-                conv=model["conv"], seed=seed,
+                **named, seed=seed,
                 eval_every=traffic["eval_every"], wire=traffic["wire"],
                 use_shard_map=traffic["shard_map"], log_fn=window)
         raise RuntimeError("the job ended before the window closed")
     except WindowClosed:
         pass
     finally:
-        for (mod, name), fn in zip(patches, saved):
+        for (mod, name, _), fn in zip(patches, saved):
             setattr(mod, name, fn)
     peak_bytes = memory_peak(devs)
     compiles_in_window = clock.count - at_open["compiles"]
@@ -296,12 +323,12 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     prog = recorder.readings(model["optimizer"]["b1"])
     del recorder
     gc.collect()
-    ref = reference.train_readings(arrays, model, seed, CHECKED_STEPS,
-                                   model["matmul_precision"])
+    ref = reference.train_readings(family, arrays, model, seed,
+                                   CHECKED_STEPS, model["matmul_precision"])
     nums = check.numbers(prog, ref)
     if keep is not None:
-        keep.update(arrays=arrays, model=model, owner=owner, ref=ref,
-                    prog=prog, nums=nums)
+        keep.update(arrays=arrays, model=model, family=family, owner=owner,
+                    ref=ref, prog=prog, nums=nums)
     correct, shown = check.judge(nums, cell["limits"])
     losses = [float(x) for x in window.losses]
     failed = sum(not math.isfinite(x) for x in losses)
@@ -314,7 +341,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
               "metrics": {}, "device": device}
     if require_chip:
         r = Run(workload=workload, config=cfg, traffic=traffic, chips=chips,
-                device_kind=dev.device_kind, peak=work.peak(dev.device_kind),
+                device_kind=dev.device_kind, family=family,
+                peak=work.peak(dev.device_kind),
                 counts=counts, in_dim=in_dim, out_dim=out_dim,
                 epochs=window.epochs, window_s=window.wall_s,
                 compiles_in_window=compiles_in_window,

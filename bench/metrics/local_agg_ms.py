@@ -1,8 +1,8 @@
 """Device time of the local aggregation per epoch, averaged over chips:
 the train step's ops under the program's ``local_agg`` scope (the ELL
-kernel with its glue, forward and transposed, on the p2p wire), read by
-``spans.py``.  Unlike ``ell_spmm_ms`` it does not depend on the kernel's
-operands.  Moves ``epoch_s``.
+row gathers and multiply-adds with their glue, forward and transposed, on
+the p2p wire), read by ``spans.py``, whatever kernel does the work.
+Moves ``epoch_s``.
 """
 
 import spans

@@ -35,7 +35,8 @@ def test_control_fails(workload):
     kept = {}
     harness.run(workload, SEED, 0.0, False, require_chip=False,
                 config_overrides=SMALL, keep=kept)
-    ctl = reference.train_readings(kept["arrays"], kept["model"], SEED,
+    ctl = reference.train_readings(kept["family"], kept["arrays"],
+                                   kept["model"], SEED,
                                    harness.CHECKED_STEPS, "high")
     ok, shown = check.judge(check.numbers(ctl, kept["ref"]),
                             harness.load("workloads", workload)["limits"])

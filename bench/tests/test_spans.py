@@ -14,10 +14,12 @@ import types
 from pathlib import Path
 
 import jax
+import numpy as np
 import pytest
 
 import harness
 import spans
+import work
 import xplane
 
 DATA = Path(__file__).parent / "data"
@@ -74,11 +76,10 @@ def test_every_op_in_one_bucket(red):
     assert 0 < red.work_s <= red.busy_s
 
 
-def test_ell_launches_are_local_aggregation(red):
+def test_ell_launches_are_local_aggregation(red, is_ell_kernel):
     # five aggregations a step (three forward, two transposed), one launch
     # per partition, four partitions, every one under local_agg
-    is_ell = metric("ell_spmm_ms").is_ell
-    ell = [op for op in red.ops if is_ell(xop(op))]
+    ell = [op for op in red.ops if is_ell_kernel(xop(op))]
     assert len(ell) == EPOCHS * 5 * 4
     assert {op.bucket for op in ell} == {"local_agg"}
     assert sum("transpose(" in op.op_name for op in ell) == EPOCHS * 2 * 4
@@ -140,12 +141,38 @@ def test_readers_on_the_scoped_trace(tmp_path, monkeypatch, red):
     assert got["local_agg_ms"] >= ell > 0
 
 
+def test_local_agg_roofline_on_the_scoped_trace(tmp_path, monkeypatch, red):
+    """The least time of the step's five local aggregations, from the
+    recorded cell's own rows and local edges, over ``local_agg_ms``."""
+    cfg = harness.load("configs", "sage3-arxiv")
+    cfg["graph"]["num_nodes"] = 1500
+    arrays = harness.graph_arrays(cfg, 2900000010)
+    n = len(arrays["indptr"]) - 1
+    owner = np.empty(n, np.int64)
+    owner[arrays["perm"]] = np.arange(n) % 4
+    run = _fake_run(tmp_path, "scoped_trace", monkeypatch)
+    run.family = harness.load_module("models", "sage")
+    run.model, run.in_dim, run.out_dim = cfg["model"], 128, 40
+    run.peak = work.peak("TPU v5 lite")
+    run.counts = work.partition_counts(arrays["indptr"], arrays["indices"],
+                                       owner, 4)
+    got = metric("local_agg_roofline").read(run)
+    least = sum(work.ell_cost(f, int(r), int(e))[1] / 819e9
+                for f in (128, 256, 256, 256, 256)
+                for r, e in zip(run.counts["rows"],
+                                run.counts["local_edges"]))
+    ms = metric("local_agg_ms").read(run)
+    assert got == pytest.approx(100 * 1e3 * least / ms)
+    assert 0 < got < 100
+
+
 def test_readers_on_an_unscoped_program(tmp_path, monkeypatch):
     """PR 13's trace, of a program that names nothing: every new reader
     finds nothing to read, as on the parent commit."""
     run = _fake_run(tmp_path, "small_trace", monkeypatch)
     assert {m: metric(m).read(run) for m in NEW_METRICS} == dict.fromkeys(
         NEW_METRICS)
+    assert metric("local_agg_roofline").read(run) is None
 
 
 def test_set_up_readers_read_the_span_table():

@@ -1,13 +1,16 @@
-"""Work counts against hand counts at the OGBN-Arxiv shapes."""
+"""Work counts against hand counts at the OGBN-Arxiv shapes: the shared
+ones (``work.py``) and the SAGE family's (``models/sage.py``)."""
 
 import numpy as np
 import pytest
 
+import harness
 import work
 
 N, E = 169_343, 2_339_410            # nodes, directed edges (seed 0)
 MODEL = {"hidden": 256, "num_layers": 3}
 V5E = work.peak("TPU v5 lite")
+SAGE = harness.load_module("models", "sage")
 
 
 def test_peaks_name_their_chip():
@@ -28,7 +31,16 @@ def test_ell_call_counts():
 
 def test_ell_calls_per_step():
     # forward 128, 256, 256; the backward transposes the last two
-    assert work.ell_calls(MODEL, 128, 40) == [128, 256, 256, 256, 256]
+    assert SAGE.local_agg_widths(MODEL, 128, 40) == [128, 256, 256, 256,
+                                                     256]
+
+
+def test_local_agg_least_sums_calls_and_partitions():
+    counts = {"rows": np.array([42_336, 42_336]),
+              "local_edges": np.array([145_948, 145_948])}
+    one = 87_871_712 / 819e9                        # F = 256, as above
+    assert work.local_agg_least(counts, [256, 256, 256], V5E) == \
+        pytest.approx(6 * one)
 
 
 def test_epoch_flops():
@@ -37,10 +49,10 @@ def test_epoch_flops():
            + 4 * N * 256 * 40 + 2 * E * 256)
     weight_grads = 4 * N * (128 * 256 + 256 * 256 + 256 * 40)
     input_grads = 4 * N * (256 * 256 + 256 * 40) + 2 * E * (256 + 256)
-    assert work.forward_flops(MODEL, N, E, 128, 40) == fwd
+    assert SAGE.forward_flops(MODEL, N, E, 128, 40) == fwd
     step = fwd + weight_grads + input_grads
-    assert work.train_step_flops(MODEL, N, E, 128, 40) == step
-    assert work.epoch_flops(MODEL, N, E, 128, 40, 5) == step + fwd / 5
+    assert SAGE.train_step_flops(MODEL, N, E, 128, 40) == step
+    assert SAGE.epoch_flops(MODEL, N, E, 128, 40, 5) == step + fwd / 5
     assert step == 203_767_874_048
 
 

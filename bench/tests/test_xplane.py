@@ -26,10 +26,10 @@ def test_busy_within_window(red):
     assert 0 < red.busy_s <= red.window_s
 
 
-def test_every_local_aggregation_is_found(red):
+def test_every_local_aggregation_is_found(red, is_ell_kernel):
     # five aggregations a step (three forward, two transposed), one
     # launch per partition, four partitions
-    ell = [op for op in red.ops if metric("ell_spmm_ms").is_ell(op)]
+    ell = [op for op in red.ops if is_ell_kernel(op)]
     assert len(ell) == EPOCHS * 5 * 4
     assert all("s32[" in op.operands.split(",")[0] for op in ell)
 
@@ -56,15 +56,15 @@ def test_breakdown_shape(red):
                for label, _ in b["idle_gaps"])
 
 
-def test_pinned_readings(red):
+def test_pinned_readings(red, is_ell_kernel):
     """Read by hand from this trace: 3,808 work ops, 10.40 ms busy."""
     assert len(red.ops) == 3808
     assert red.busy_s == pytest.approx(0.010399775)
-    assert red.op_seconds(metric("ell_spmm_ms").is_ell) == pytest.approx(
+    assert red.op_seconds(is_ell_kernel) == pytest.approx(
         5 * 0.1456072e-3)
 
 
-def test_op_text_parsing():
+def test_op_text_parsing(is_ell_kernel):
     op = xplane.Op(0, '%closed_call.54 = f32[42368,256]{1,0:T(8,128)} '
                    'custom-call(s32[42368,15]{1,0} %pad.1, f32[42368,15]'
                    '{1,0} %pad.2, f32[42496,256]{1,0} %pad.3), '
@@ -73,7 +73,7 @@ def test_op_text_parsing():
     assert op.shape == "f32[42368,256]{1,0:T(8,128)}"
     assert op.opcode == "custom-call"
     assert op.operands.startswith("s32[42368,15]")
-    assert metric("ell_spmm_ms").is_ell(op)
+    assert is_ell_kernel(op)
     tup = xplane.Op(0, "%while.2 = (s32[], f32[4,8]{1,0}) while((s32[], "
                     "f32[4,8]{1,0}) %tuple.1), condition=%c, body=%b", 0, 1)
     assert tup.opcode == "while" and tup.shape == "(s32[], f32[4,8]{1,0})"

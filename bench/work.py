@@ -1,5 +1,8 @@
 """Work counts: the operations and bytes that the algorithm needs, from the
-graph's real rows and edges and the model's widths.
+graph's real rows and edges and the model's widths.  The parts every
+model family shares; a family (``models/<family>.py``) counts its own
+``epoch_flops`` and the widths of its local aggregations
+(``local_agg_widths``).
 
 Nothing here looks at how a kernel tiles its grid or pads its degrees, so
 the counts stay valid when a kernel is replaced.  A partition's "local"
@@ -46,19 +49,6 @@ def partition_counts(indptr: np.ndarray, indices: np.ndarray,
             "edges": len(indices), "nodes": n}
 
 
-def layer_widths(model: dict, in_dim: int, out_dim: int):
-    ds = [in_dim] + [model["hidden"]] * (model["num_layers"] - 1) + [out_dim]
-    return list(zip(ds[:-1], ds[1:]))
-
-
-def ell_calls(model: dict, in_dim: int, out_dim: int) -> list[int]:
-    """Feature width of each local aggregation in one training step: one
-    per layer forward, and its transpose in the backward for every layer
-    whose input depends on the weights (all but the first)."""
-    fwd = [d_in for d_in, _ in layer_widths(model, in_dim, out_dim)]
-    return fwd + fwd[1:]
-
-
 def ell_cost(f: int, rows: int, local_edges: int) -> tuple[float, float]:
     """``(flops, bytes)`` of one partition's local aggregation at width
     ``f``: a multiply-add per edge and feature; read every source row and
@@ -74,43 +64,10 @@ def least_seconds(flops: float, nbytes: float, pk: dict) -> tuple[float, str]:
     return (t_c, "compute") if t_c >= t_m else (t_m, "memory")
 
 
-def ell_least_per_epoch(counts: dict, model: dict, in_dim: int,
-                        out_dim: int, pk: dict) -> tuple[float, str]:
-    """Least seconds of one training step's local aggregations, summed
-    over calls and partitions (each partition is its own launch)."""
-    total, bounds = 0.0, set()
-    for f in ell_calls(model, in_dim, out_dim):
-        for rows, loc in zip(counts["rows"], counts["local_edges"]):
-            t, b = least_seconds(*ell_cost(f, int(rows), int(loc)), pk)
-            total += t
-            bounds.add(b)
-    return total, "+".join(sorted(bounds))
-
-
-def forward_flops(model: dict, n: int, e: int, in_dim: int,
-                  out_dim: int) -> float:
-    """One forward pass over the whole graph: per layer two dense
-    products ``2 N d_in d_out`` (self and neighbour) and the aggregation
-    ``2 E d_in``."""
-    return sum(4.0 * n * a * b + 2.0 * e * a
-               for a, b in layer_widths(model, in_dim, out_dim))
-
-
-def train_step_flops(model: dict, n: int, e: int, in_dim: int,
-                     out_dim: int) -> float:
-    """Forward and backward of one full-graph training step.  The
-    backward takes the weight gradients of every layer (``2 N d_in d_out``
-    per product) and the input gradients of all but the first layer
-    (another two products and the transposed aggregation)."""
-    dims = layer_widths(model, in_dim, out_dim)
-    bwd = sum(4.0 * n * a * b for a, b in dims)
-    bwd += sum(4.0 * n * a * b + 2.0 * e * a for a, b in dims[1:])
-    return forward_flops(model, n, e, in_dim, out_dim) + bwd
-
-
-def epoch_flops(model: dict, n: int, e: int, in_dim: int, out_dim: int,
-                eval_every: int) -> float:
-    """A training epoch with its share of the periodic evaluation's
-    forward pass."""
-    return (train_step_flops(model, n, e, in_dim, out_dim)
-            + forward_flops(model, n, e, in_dim, out_dim) / eval_every)
+def local_agg_least(counts: dict, widths: list[int], pk: dict) -> float:
+    """Least seconds of one training step's local aggregations at the
+    feature ``widths``, summed over calls and partitions (each partition
+    is its own call)."""
+    return sum(least_seconds(*ell_cost(f, int(rows), int(loc)), pk)[0]
+               for f in widths
+               for rows, loc in zip(counts["rows"], counts["local_edges"]))
